@@ -54,11 +54,12 @@ def plan_for(mesh: str | None):
     """``DxM`` -> a TP ShardPlan on a (data, model) dev mesh (shards the
     paged pool over KV heads and params per the plan); None/"" -> the
     mesh-less single-device plan."""
+    from repro.launch.mesh import make_mesh
     from repro.sharding import ShardPlan, make_plan
     if not mesh:
         return ShardPlan(mesh=None)
     d, m = (int(x) for x in mesh.split("x"))
-    return make_plan(jax.make_mesh((d, m), ("data", "model")), "tp")
+    return make_plan(make_mesh((d, m), ("data", "model")), "tp")
 
 
 def bench_cell(lm, params, plan, *, slots: int, quantized: bool,

@@ -25,16 +25,7 @@ def train_once(prior: bool, quantize: bool, steps: int = 400, lr=3e-3):
     xs, ys = fashion_like(4096, seed=1)
     xq, yq = fashion_like(1024, seed=2)
 
-    @jax.jit
-    def step(params, opt, batch):
-        loss, grads = jax.value_and_grad(MLP.mlp_loss, allow_int=True)(
-            params, batch, d)
-        params, opt = A.adam_update(params, grads, opt, jnp.asarray(lr), tcfg)
-        if d.tt.rank_adapt:
-            params = MLP.mlp_lambda_update(params, d)
-        if d.qc.enable:
-            params = MLP.mlp_scale_update(params, batch, grads, d)
-        return params, opt, loss
+    step = jax.jit(MLP.mlp_train_step(d, tcfg))
 
     bsz = 64
     for i in range(steps):

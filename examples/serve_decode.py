@@ -5,11 +5,14 @@ archs, a slot-indexed quantized recurrent-state cache (attention sublayers
 hit the KV pool, SSM/RWKV sublayers hit the state cache; one engine serves
 every decoder family in the zoo):
 
-    PYTHONPATH=src python examples/serve_decode.py --arch internlm2-1.8b
-    PYTHONPATH=src python examples/serve_decode.py --arch internlm2-1.8b --quantized
-    PYTHONPATH=src python examples/serve_decode.py --arch deepseek-v2-236b --temperature 0.8
-    PYTHONPATH=src python examples/serve_decode.py --arch rwkv6-1.6b --quantized
-    PYTHONPATH=src python examples/serve_decode.py --arch jamba-1.5-large
+    PYTHONPATH=src python examples/serve_decode.py --arch internlm2-1.8b --quantized --fused
+    PYTHONPATH=src python examples/serve_decode.py --arch internlm2-1.8b --reduced
+    PYTHONPATH=src python examples/serve_decode.py --arch deepseek-v2-236b --reduced --temperature 0.8
+    PYTHONPATH=src python examples/serve_decode.py --arch rwkv6-1.6b --reduced --quantized
+    PYTHONPATH=src python examples/serve_decode.py --arch jamba-1.5-large --reduced
+
+Published widths in the config's dtype by default (sized for a chip);
+``--reduced`` runs the f32 toy widths of ``get_reduced`` (the CPU size).
 """
 import argparse
 import json
@@ -18,8 +21,9 @@ import time
 import numpy as np
 import jax
 
-import repro.configs as C
 from repro.models import build_lm, init_lm
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.train import get_model_cfg
 from repro.serve import (Engine, EngineConfig, PoolConfig, SamplingParams)
 from repro.sharding import ShardPlan
 
@@ -27,6 +31,9 @@ from repro.sharding import ShardPlan
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="get_reduced toy widths in f32 (default: published "
+                         "widths in the config's dtype)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -44,7 +51,8 @@ def main():
                          "dequant; MLA sublayers fall back to gather)")
     args = ap.parse_args()
 
-    cfg = C.get_reduced(args.arch).replace(dtype="float32", remat="none")
+    enable_compile_cache()
+    cfg, _ = get_model_cfg(args.arch, args.reduced)
     if cfg.is_encoder:
         raise SystemExit(f"{args.arch} is encoder-only — no decode path")
     if cfg.frontend != "none":

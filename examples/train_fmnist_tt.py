@@ -33,17 +33,8 @@ def main():
     xs, ys = fashion_like(8192, seed=1)
     xt, yt = fashion_like(2048, seed=2)
 
-    @jax.jit
-    def step(params, opt, batch):
-        loss, grads = jax.value_and_grad(MLP.mlp_loss, allow_int=True)(
-            params, batch, d)
-        params, opt = A.adam_update(params, grads, opt,
-                                    jnp.asarray(3e-3), tcfg)
-        if d.tt.rank_adapt:
-            params = MLP.mlp_lambda_update(params, d)       # Eq. (4)
-        if d.qc.enable:
-            params = MLP.mlp_scale_update(params, batch, grads, d)  # §3.3
-        return params, opt, loss
+    # Adam + Eq. (4) λ update + §3.3 scale manager
+    step = jax.jit(MLP.mlp_train_step(d, tcfg))
 
     bsz, t0 = 64, time.time()
     for i in range(args.steps):

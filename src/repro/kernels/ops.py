@@ -210,12 +210,12 @@ def paged_attention(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
             and q.shape[-2] % hkv == 0:
         from jax.sharding import PartitionSpec as P
 
-        from ..sharding import compat_shard_map
+        from ..sharding import shard_map
         # q's head axis is -2 in both ranks: (B, Hq, Dh) decode or
         # (B, S, Hq, Dh) q-block
         qspec = (P(None, "model", None) if q.ndim == 3
                  else P(None, None, "model", None))
-        f = compat_shard_map(
+        f = shard_map(
             f, plan.mesh,
             in_specs=(qspec,                           # q
                       P(None, None, "model", None),    # k pages

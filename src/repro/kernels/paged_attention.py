@@ -22,9 +22,10 @@ Two implementations of the same dataflow:
   operands — the BlockSpec index map chases the slot's page pointers, so
   each grid step DMAs exactly one int8 K and V page into VMEM, dequantizes
   with the slot's pow-2 scale in-register, and folds the page into the
-  (m, l, acc) online-softmax state (now q-tiled: (S, Hq, ...)) held in VMEM
-  scratch.  Grid steps for pages entirely above the block's LAST row
-  (``lens[slot] + S - 1``) are predicated out (``pl.when``): a fully-masked
+  (m, l, acc) online-softmax state (one column per q row and query head,
+  see ``_block_update``) held in VMEM scratch.  Grid steps for pages
+  entirely above the block's LAST row (``lens[slot] + S - 1``) are
+  predicated out (``pl.when``): a fully-masked
   page is the exact identity update, so short slots in a ragged batch skip
   their tail pages' dequant + MXU work for free (the grid is sized by
   ``pages_per_slot``, i.e. the longest possible slot).  Runs compiled on
@@ -66,11 +67,12 @@ Layouts (one attention sublayer, one layer of the scanned stack):
             at pos <= lens + j; unmapped pages sit entirely above the last
             row, so the mask also excludes trash-page junk for active slots)
 
-TPU alignment note: compiled runs want Dh a multiple of 128 and page a
-multiple of 8 (f32 sublane); the interpret path takes any shape.  The
-wrapper in ``kernels/ops.py`` picks the implementation and leaves the pool
-layout untouched — padding the pool per step would re-materialize exactly
-the traffic this kernel exists to avoid.
+TPU note: the kernel compiles for v5e at internlm2-1.8b widths (Hkv=8,
+Dh=128, page 16, int8 and bf16 pages; tests/test_tpu_compile.py); the
+interpret path takes any shape.  The wrapper in ``kernels/ops.py`` picks
+the implementation and leaves the pool layout untouched — padding the pool
+per step would re-materialize exactly the traffic this kernel exists to
+avoid.
 """
 from __future__ import annotations
 
@@ -95,36 +97,61 @@ def _norm_q(q: jax.Array):
     raise ValueError(f"q must be rank 3 or 4, got {q.shape}")
 
 
-def _block_update(m, l, acc, qf, k, v, base_pos, limit, scale):
+def _block_update(m, l, acc, qt, k, v, base_pos, first, scale, *, g):
     """One online-softmax step, shared VERBATIM by the Pallas kernel body
-    (b=1, one page) and the jnp page-scan (full batch, a chunk of pages) —
-    identical einsum shapes modulo the batch/page-chunk dims, which the
-    CPU/interpret lowering treats as outer loops, is what keeps the two
-    implementations bitwise-locked.
+    (one slot, one page) and the jnp page-scan (a leading batch dim, a
+    chunk of pages) — identical contractions modulo that leading dim, which
+    the CPU/interpret lowering treats as an outer loop, is what keeps the
+    two implementations bitwise-locked.
 
-    qf: (b, S, Hkv, g, Dh) f32 queries in the grouped-head layout; k/v:
-    (b, cp, Hkv, Dh) f32 (already dequantized, ``cp`` key positions
-    starting at ``base_pos``); limit: (b, S) per-row causal limits (row j
-    attends pos <= limit[:, j]); m/l: (b, S, Hq, 1); acc: (b, S, Hq, Dh).
-    KV heads are never expanded: scores and values use grouped einsums over
-    the (Hkv, g) query layout."""
-    b, sq, hkv, g, dh = qf.shape
-    cp = k.shape[1]
-    hq = hkv * g
-    s = jnp.einsum("bshgd,bphd->bshgp", qf, k,
+    Query-minor, head-major layout, so every contraction is a plain
+    (rows x K) @ (K x cols) matmul with ONE batch dim, the KV head (the
+    TPU's Mosaic matmul supports no more): qt (..., Hkv, Dh, M) holds KV
+    head h's g query heads for every q row as columns, column c = s*g + j
+    at position ``first + s``; k/v (..., Hkv, n, Dh) hold n key positions
+    from ``base_pos``. Scores come out transposed, (..., Hkv, n, M), and
+    the softmax runs over the key axis -2. KV heads are never expanded.
+    (The (keys x K) @ (K x queries) form is also the one XLA's CPU backend
+    lowers ``models/attention.py::gqa_attend``'s grouped einsums to, so the
+    off-TPU fused path and the gather path round alike.)
+
+    first: the slot's first q-row position, broadcastable against
+    (..., 1, 1, 1); m/l: (..., Hkv, 1, M); acc: (..., Hkv, Dh, M)."""
+    n, cols = k.shape[-2], qt.shape[-1]
+    s = jnp.einsum("...hnd,...hdm->...hnm", k, qt,
                    preferred_element_type=jnp.float32) * scale
-    pos = base_pos + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, 1, 1, cp), 4)
-    s = jnp.where(pos <= limit[:, :, None, None, None], s, NEG_INF)
-    s = s.reshape(b, sq, hq, cp)
-    m_new = jnp.maximum(m, jnp.max(s, axis=3, keepdims=True))
+    kpos = base_pos + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+    qrow = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1) // g
+    s = jnp.where(kpos <= first + qrow, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-2, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m - m_new)
-    l_new = l * corr + jnp.sum(p, axis=3, keepdims=True)
+    l_new = l * corr + jnp.sum(p, axis=-2, keepdims=True)
     acc_new = acc * corr + jnp.einsum(
-        "bshgp,bphd->bshgd", p.reshape(b, sq, hkv, g, cp), v,
-        preferred_element_type=jnp.float32).reshape(b, sq, hq, dh)
+        "...hdn,...hnm->...hdm", jnp.swapaxes(v, -1, -2), p,
+        preferred_element_type=jnp.float32)
     return m_new, l_new, acc_new
+
+
+def _to_cols(q: jax.Array, hkv: int) -> jax.Array:
+    """(B, S, Hq, Dh) -> (B, Hkv, Dh, S*g): each KV head's query heads as
+    columns (GQA groups the query heads contiguously per KV head)."""
+    b, sq, hq, dh = q.shape
+    g = hq // hkv
+    q = q.reshape(b, sq, hkv, g, dh).transpose(0, 2, 4, 1, 3)
+    return q.reshape(b, hkv, dh, sq * g)
+
+
+def _from_cols(o: jax.Array, sq: int) -> jax.Array:
+    """Inverse of ``_to_cols``."""
+    b, hkv, dh, cols = o.shape
+    g = cols // sq
+    o = o.reshape(b, hkv, dh, sq, g).transpose(0, 3, 1, 4, 2)
+    return o.reshape(b, sq, hkv * g, dh)
+
+
+def _pow2(scale_log2) -> jax.Array:
+    return jnp.exp2(jnp.asarray(scale_log2, jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -151,29 +178,22 @@ def _pa_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
     # the longest).
     @pl.when(p * page_size <= lens_ref[b] + (q_rows - 1))
     def _update():
-        q = q_ref[0].astype(jnp.float32)                # (S, Hq, Dh)
-        k = k_ref[...]                                  # (1, page, Hkv, Dh)
-        v = v_ref[...]
+        k = k_ref[0].astype(jnp.float32)                # (page, Hkv, Dh)
+        v = v_ref[0].astype(jnp.float32)
         if quantized:
-            # in-kernel pow-2 dequant: one multiply per element, straight
-            # from the int8 page in VMEM — no fp32 page ever round-trips
-            # through HBM
-            k = k.astype(jnp.float32) * jnp.exp2(ks_ref[b])
-            v = v.astype(jnp.float32) * jnp.exp2(vs_ref[b])
-        else:
-            k = k.astype(jnp.float32)
-            v = v.astype(jnp.float32)
-        sq, hq, dh = q.shape
-        hkv = k.shape[2]
-        qf = q.reshape(1, sq, hkv, groups, dh)
-        limit = lens_ref[b] + jax.lax.broadcasted_iota(
-            jnp.int32, (1, sq), 1)
+            # in-kernel pow-2 dequant: one multiply per element by the
+            # slot's 2^scale (exponentiated by the wrapper), straight from
+            # the int8 page in VMEM — no fp32 page round-trips through HBM
+            k = k * ks_ref[b]
+            v = v * vs_ref[b]
         m_new, l_new, acc_new = _block_update(
-            m_ref[...][None], l_ref[...][None], acc_ref[...][None],
-            qf, k, v, p * page_size, limit, scale)
-        m_ref[...] = m_new[0]
-        l_ref[...] = l_new[0]
-        acc_ref[...] = acc_new[0]
+            m_ref[...], l_ref[...], acc_ref[...],
+            q_ref[0].astype(jnp.float32), jnp.swapaxes(k, 0, 1),
+            jnp.swapaxes(v, 0, 1), p * page_size, lens_ref[b], scale,
+            g=groups)
+        m_ref[...] = m_new
+        l_ref[...] = l_new
+        acc_ref[...] = acc_new
 
     @pl.when(p == num_pages - 1)
     def _emit():
@@ -193,11 +213,12 @@ def paged_attention_kernel(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
     pp = table.shape[1]
     hkv = kdata.shape[2]
     assert hq % hkv == 0, (hq, hkv)
+    cols = sq * (hq // hkv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,              # page table + length vector
         grid=(b, pp),
         in_specs=[
-            pl.BlockSpec((1, sq, hq, dh),
+            pl.BlockSpec((1, hkv, dh, cols),
                          lambda bi, pi, tab, ln: (bi, 0, 0, 0)),
             # the page-pointer chase: block (pi of slot bi) is physical page
             # tab[bi, pi] — unmapped entries point at the trash page, whose
@@ -209,12 +230,12 @@ def paged_attention_kernel(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, sq, hq, dh),
+        out_specs=pl.BlockSpec((1, hkv, dh, cols),
                                lambda bi, pi, tab, ln: (bi, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((sq, hq, 1), jnp.float32),       # running max
-            pltpu.VMEM((sq, hq, 1), jnp.float32),       # running denom
-            pltpu.VMEM((sq, hq, dh), jnp.float32),      # running numerator
+            pltpu.VMEM((hkv, 1, cols), jnp.float32),    # running max
+            pltpu.VMEM((hkv, 1, cols), jnp.float32),    # running denom
+            pltpu.VMEM((hkv, dh, cols), jnp.float32),   # running numerator
         ],
     )
     kern = functools.partial(
@@ -223,10 +244,11 @@ def paged_attention_kernel(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq, hq, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, dh, cols), q.dtype),
         interpret=interpret,
-    )(table, lens, q, kdata, vdata,
-      jnp.asarray(kscale, jnp.float32), jnp.asarray(vscale, jnp.float32))
+    )(table, lens, _to_cols(q, hkv), kdata, vdata,
+      _pow2(kscale), _pow2(vscale))
+    out = _from_cols(out, sq)
     return out[:, 0] if squeeze else out
 
 
@@ -246,13 +268,12 @@ def paged_attention_jnp(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
     bit-lock the differential tests assert); larger chunks amortize the
     scan's dispatch overhead on non-TPU backends while peak residency stays
     bounded by the chunk — the (B, max_len, *feat) fp32 slot view is never
-    materialized either way.  KV heads are never expanded: scores and
-    values use grouped einsums over the (Hkv, g) query layout."""
+    materialized either way.  KV heads are never expanded: queries and
+    pages take the kernel's layout (``_block_update``)."""
     q, squeeze = _norm_q(q)
     b, sq, hq, dh = q.shape
     pp = table.shape[1]
     hkv = kdata.shape[2]
-    g = hq // hkv
     scale = 1.0 / math.sqrt(dh)
     c = max(1, min(page_chunk, pp))
     nsteps = -(-pp // c)
@@ -267,31 +288,29 @@ def paged_attention_jnp(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
         trash = kdata.shape[0] - 1
         table = jnp.pad(table, ((0, 0), (0, nsteps * c - pp)),
                         constant_values=trash)
-    qf = q.astype(jnp.float32).reshape(b, sq, hkv, g, dh)
-    ks = jnp.exp2(jnp.asarray(kscale, jnp.float32))
-    vs = jnp.exp2(jnp.asarray(vscale, jnp.float32))
-    # per-row causal limits: row j of the q-block attends pos <= lens + j
-    limit = lens[:, None] + jnp.arange(sq)[None, :]         # (B, S)
+    qt = _to_cols(q.astype(jnp.float32), hkv)
+    ks = _pow2(kscale)[:, None, None, None, None]
+    vs = _pow2(vscale)[:, None, None, None, None]
+    first = lens[:, None, None, None]
+    n = c * page_size
+
+    def heads(x):                               # (B, c, page, Hkv, Dh) ->
+        return x.reshape(b, n, hkv, dh).transpose(0, 2, 1, 3)  # (B,Hkv,n,Dh)
 
     def body(carry, step):
         m, l, acc = carry
         pages = jax.lax.dynamic_slice_in_dim(table, step * c, c, axis=1)
-        k = kdata[pages]                        # (B, c, page, Hkv, Dh)
-        v = vdata[pages]
+        k = kdata[pages].astype(jnp.float32)
+        v = vdata[pages].astype(jnp.float32)
         if quantized:
-            k = k.astype(jnp.float32) * ks[:, None, None, None, None]
-            v = v.astype(jnp.float32) * vs[:, None, None, None, None]
-        else:
-            k = k.astype(jnp.float32)
-            v = v.astype(jnp.float32)
-        k = k.reshape(b, c * page_size, hkv, dh)
-        v = v.reshape(b, c * page_size, hkv, dh)
-        return _block_update(m, l, acc, qf, k, v, step * (c * page_size),
-                             limit, scale), None
+            k = k * ks
+            v = v * vs
+        return _block_update(m, l, acc, qt, heads(k), heads(v), step * n,
+                             first, scale, g=hq // hkv), None
 
-    m0 = jnp.full((b, sq, hq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, sq, hq, 1), jnp.float32)
-    a0 = jnp.zeros((b, sq, hq, dh), jnp.float32)
+    m0 = jnp.full((b, hkv, 1, qt.shape[-1]), NEG_INF, jnp.float32)
+    l0 = jnp.zeros(m0.shape, jnp.float32)
+    a0 = jnp.zeros(qt.shape, jnp.float32)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), jnp.arange(nsteps))
-    out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
+    out = _from_cols((acc / jnp.maximum(l, 1e-30)).astype(q.dtype), sq)
     return out[:, 0] if squeeze else out

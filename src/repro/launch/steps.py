@@ -140,12 +140,17 @@ def _ce_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
     return jnp.sum(ce) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
-def make_loss_fn(lm: LMDef, plan: ShardPlan, tcfg: TrainConfig):
+def make_loss_fn(lm: LMDef, plan: ShardPlan, tcfg: TrainConfig,
+                 batch_shards: int = 1):
     """Loss over one batch. ``loss_fn(params, batch, scales=None)``: with a
     managed scale-state tree (``TrainState.scales``) the forward runs the
     policy's ``activation`` quant edges and the aux output carries the
     observed activation statistic alongside the metrics:
-    ``loss, (metrics, obs) = loss_fn(...)``."""
+    ``loss, (metrics, obs) = loss_fn(...)``.
+
+    ``batch_shards``: how many replicas each see one shard of the global
+    batch (the dp step). The prior is scaled per token of the GLOBAL batch,
+    so it is the same on every replica and matches the one-device loss."""
     cfg = lm.cfg
 
     def loss_fn(params, batch, scales=None):
@@ -173,7 +178,8 @@ def make_loss_fn(lm: LMDef, plan: ShardPlan, tcfg: TrainConfig):
         if cfg.tt.enable and cfg.tt.rank_adapt:
             # Eq. (1): CE mean + prior; prior scaled per-token so its
             # gradient pressure is batch-size independent.
-            denom = float(labels.shape[0] * labels.shape[1]) * tcfg.total_steps
+            denom = (float(labels.shape[0] * labels.shape[1] * batch_shards)
+                     * tcfg.total_steps)
             prior = lm_prior_loss(params, lm) / denom
         metrics = {"ce": ce, "aux": aux, "prior": prior}
         return loss + prior, (metrics, obs)
@@ -282,13 +288,13 @@ def make_dp_train_step(lm: LMDef, plan: ShardPlan, tcfg: TrainConfig):
         raise ValueError("the dp shard_map step IS the int8 wire — "
                          "enable tcfg.grad_compress")
     from ..optim.grad_compress import psum_int8_tree
-    from ..sharding import compat_shard_map
+    from ..sharding import shard_map
     # the body sees per-replica local shards: the model runs mesh-less
     # (a with_sharding_constraint cannot reference manual mesh axes)
-    loss_fn = make_loss_fn(lm, ShardPlan(mesh=None), tcfg)
-    policy = lm.cfg.quant.policy()
     axis = plan.dp_axis()
     ndev = plan.dp_size()
+    loss_fn = make_loss_fn(lm, ShardPlan(mesh=None), tcfg, batch_shards=ndev)
+    policy = lm.cfg.quant.policy()
     wire_spec = policy.spec_for("dp_wire")
 
     def is_f(g):
@@ -337,9 +343,9 @@ def make_dp_train_step(lm: LMDef, plan: ShardPlan, tcfg: TrainConfig):
     def train_step(state: TrainState, batch):
         batch_specs = jax.tree.map(
             lambda b: P(plan.dp_axes, *([None] * (jnp.ndim(b) - 1))), batch)
-        f = compat_shard_map(local_step, plan.mesh,
-                             in_specs=(state_specs, batch_specs),
-                             out_specs=(state_specs, P()))
+        f = shard_map(local_step, plan.mesh,
+                      in_specs=(state_specs, batch_specs),
+                      out_specs=(state_specs, P()))
         return f(state, batch)
 
     return train_step

@@ -12,9 +12,7 @@ straggler monitor, prefetching input pipeline.
 from __future__ import annotations
 
 import argparse
-import math
 import time
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -25,12 +23,12 @@ from ..ckpt import (AsyncCheckpointer, install_preemption_handler,
                     latest_step, load, step_path)
 from ..configs.base import ModelConfig, TrainConfig
 from ..data import Prefetcher, host_shard_info, lm_batch
-from ..models.frontend import synth_audio_frames, synth_vision_patches
 from ..models.lm import build_lm, init_lm, lm_param_counts
 from ..sharding import make_plan
-from ..launch.steps import (TrainState, init_dp_train_state,
-                            init_train_state, make_dp_train_step,
-                            make_train_step)
+from .compile_cache import enable_compile_cache
+from .mesh import make_dp_mesh, make_mesh
+from .steps import (init_dp_train_state, init_train_state,
+                    make_dp_train_step, make_train_step)
 
 # a ~100M-param dense config for the end-to-end example driver
 LM100M = ModelConfig(name="lm100m", num_layers=12, d_model=768, num_heads=12,
@@ -234,6 +232,7 @@ def main():
                     help="write per-step train_step trace events (JSONL)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg, strategy = get_model_cfg(args.arch, args.reduced)
     if args.tt:
         cfg = C.with_tt(cfg, max_rank=32)
@@ -249,9 +248,8 @@ def main():
     if args.mesh:
         if "x" in args.mesh:
             d, m = (int(x) for x in args.mesh.split("x"))
-            mesh = jax.make_mesh((d, m), ("data", "model"))
+            mesh = make_mesh((d, m), ("data", "model"))
         else:
-            from .mesh import make_dp_mesh
             mesh = make_dp_mesh(int(args.mesh))
     trace = None
     if args.trace_out:

@@ -116,6 +116,27 @@ def mlp_scale_update(params: dict, batch: dict, grads: dict, d: MLPDef) -> dict:
     return new
 
 
+def mlp_train_step(d: MLPDef, tcfg):
+    """The paper's training step (Appendix B): loss and gradients, an Adam
+    update, then the Eq. (4) λ update (rank-adaptive prior) and the §3.3
+    scale-manager step (quantized runs). Returns the unjitted
+    ``step(params, opt, batch) -> (params, opt, loss)``."""
+    from ..optim import adam as A
+
+    def step(params, opt, batch):
+        loss, grads = jax.value_and_grad(mlp_loss, allow_int=True)(
+            params, batch, d)
+        params, opt = A.adam_update(params, grads, opt,
+                                    jnp.asarray(tcfg.learning_rate), tcfg)
+        if d.tt.rank_adapt:
+            params = mlp_lambda_update(params, d)
+        if d.qc.enable:
+            params = mlp_scale_update(params, batch, grads, d)
+        return params, opt, loss
+
+    return step
+
+
 # ---------------------------------------------------------------------------
 # Table-1 accounting (analytic)
 # ---------------------------------------------------------------------------

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
+from ..sharding import shard_map
 from .common import SiteDef, apply_site, init_site, make_site, silu
 
 
@@ -116,12 +117,6 @@ def _route(params, x2d, d: MoEDef, cfg: ModelConfig, mask=None):
         p_e = jnp.mean(probs, axis=0)
     aux = e * jnp.sum(f_e * p_e)
     return topk_idx, topk_w.astype(x2d.dtype), aux
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map (shared shim: see sharding.py)."""
-    from ..sharding import compat_shard_map
-    return compat_shard_map(f, mesh, in_specs, out_specs)
 
 
 def _expert_glu(eparams, xe, d: MoEDef, cfg: ModelConfig):
@@ -235,7 +230,7 @@ def moe_forward(params: dict, x: jax.Array, d: MoEDef, cfg: ModelConfig, *,
                                             scatter_dimension=1, tiled=True)
             return jax.lax.psum(out_loc, ep_axis)
 
-        out = _shard_map(
+        out = shard_map(
             shard_fn, mesh,
             (tok_spec, tok_spec, tok_spec,
              jax.tree.map(lambda _: P(ep_axis), eparams)),

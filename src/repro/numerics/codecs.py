@@ -185,7 +185,10 @@ class BlockwiseReference:
             v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, pad)])
         blocks = v.reshape(v.shape[:-1] + (nb, b))
         qmax = spec.qmax
-        sc = jnp.max(jnp.abs(blocks), axis=-1) / qmax
+        # times the f32 reciprocal, not "/ qmax": XLA's CPU code generator
+        # may turn a division by a constant into this multiply in one fusion
+        # and not in another, so the backends would round apart
+        sc = jnp.max(jnp.abs(blocks), axis=-1) * jnp.float32(1.0 / qmax)
         q = jnp.round(blocks / jnp.maximum(sc, 1e-20)[..., None])
         codes = jnp.clip(q, -qmax, qmax).astype(spec.jnp_storage)
         return QTensor(codes.reshape(v.shape[:-1] + (nb * b,)), sc, spec,

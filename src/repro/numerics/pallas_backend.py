@@ -449,7 +449,8 @@ class Pow2Pallas(Pow2Reference):
 
 def _bw_enc_kernel(x_ref, q_ref, s_ref, *, qmax: float):
     x = x_ref[...].astype(jnp.float32)                 # (bm, b)
-    sc = jnp.max(jnp.abs(x), axis=1, keepdims=True) / qmax
+    # the reference codec's exact expression (see BlockwiseReference)
+    sc = jnp.max(jnp.abs(x), axis=1, keepdims=True) * jnp.float32(1.0 / qmax)
     q = jnp.round(x / jnp.maximum(sc, 1e-20))
     q_ref[...] = jnp.clip(q, -qmax, qmax).astype(q_ref.dtype)
     s_ref[...] = sc

@@ -29,7 +29,7 @@ Two entry points:
 
 Usage (inside the jitted train step, before the optimizer):
     grads_c, residual = compress_decompress(grads, residual)
-or, under ``sharding.compat_shard_map`` over the plan's dp axes:
+or, under ``sharding.shard_map`` over the plan's dp axes:
     grads_sum, residual = psum_int8_tree(grads, residual, plan.dp_axis())
 """
 from __future__ import annotations

@@ -23,17 +23,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map: ``jax.shard_map`` (new API, check_vma)
-    with fallback to ``jax.experimental.shard_map`` (check_rep). One shim
-    for every explicit-collective site (MoE expert dispatch, the int8
-    gradient wire)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checks off — one wrapper for every
+    explicit-collective site (MoE expert dispatch, the int8 gradient wire,
+    the head-sharded page walk)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _div(n: int, mesh: Mesh | None, axis) -> bool:

@@ -2,12 +2,14 @@
 
 Each case runs in a subprocess (the device count must be set before jax
 initializes) and asserts that the sharded computation matches the
-single-device reference: TP, CP, EP (shard_map MoE), and the sharded train
-step.
+single-device reference: TP, CP, EP (shard_map MoE), the sharded train
+step, the int8 gradient wire, and the dp-only wire step on a TT model with
+the rank prior.
 """
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
@@ -19,11 +21,12 @@ from repro.configs.base import ModelConfig, MoEConfig, TrainConfig
 from repro.models import build_lm, init_lm, lm_forward
 from repro.models import moe as M
 from repro.sharding import ShardPlan, make_plan
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import init_train_state, make_train_step
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 CASE = "%s"
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 
 if CASE in ("tp", "cp"):
     cfg = ModelConfig(name="t", num_layers=2, d_model=64, num_heads=4,
@@ -65,7 +68,7 @@ elif CASE == "wire":
     from jax.sharding import Mesh
     from repro.optim.grad_compress import WIRE_SPEC, psum_int8_tree
     from repro.numerics.codecs import blockwise_geometry
-    from repro.sharding import ShardPlan, compat_shard_map
+    from repro.sharding import ShardPlan, shard_map
 
     plan = ShardPlan(mesh=None, dp_axes=("data",))
     assert plan.dp_axis() == "data" and ShardPlan(
@@ -91,7 +94,7 @@ elif CASE == "wire":
             jax.tree_util.tree_structure(g1), list(nr))
         return out, jax.tree.map(lambda a: a[None], nr_tree)
 
-    f = compat_shard_map(local, mesh2, in_specs=(P("data"), P("data")),
+    f = shard_map(local, mesh2, in_specs=(P("data"), P("data")),
                          out_specs=(P(), P("data")))
     out, nres = jax.jit(f)(gs, rs)
 
@@ -166,20 +169,51 @@ elif CASE == "train":
     np.testing.assert_allclose(float(m_sh["loss"]), float(m_ref["loss"]),
                                rtol=2e-3)
     print("OK train", float(m_sh["loss"]))
+
+elif CASE == "dp_tt":
+    # the dp-only int8-wire step on a TT model with the rank prior: each
+    # replica sees 1/8 of the batch, but the prior is per token of the
+    # global batch, so the step-1 loss (pre-update) is the one-device loss
+    from repro.configs.base import TTConfig
+    from repro.launch.mesh import make_dp_mesh
+    from repro.launch.steps import init_dp_train_state, make_dp_train_step
+    cfg = ModelConfig(name="t", num_layers=2, d_model=32, num_heads=2,
+                      num_kv_heads=2, d_ff=64, vocab_size=64, remat="none",
+                      dtype="float32",
+                      tt=TTConfig(enable=True, d=3, max_rank=4,
+                                  min_elements=1024))
+    lm = build_lm(cfg)
+    tcfg = TrainConfig(total_steps=4, warmup_steps=1, grad_compress=True)
+    params = init_lm(jax.random.PRNGKey(0), lm)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 64),
+             "labels": jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0, 64)}
+    _, m_ref = jax.jit(make_train_step(lm, ShardPlan(mesh=None), tcfg))(
+        init_train_state(params, tcfg), batch)
+    plan = make_plan(make_dp_mesh(8), "tp")
+    _, m_dp = jax.jit(make_dp_train_step(lm, plan, tcfg))(
+        init_dp_train_state(params, tcfg, plan), batch)
+    # a per-replica prior would add 7x this to the dp loss
+    assert float(m_ref["prior"]) > 0.1 * float(m_ref["ce"]), m_ref
+    np.testing.assert_allclose(float(m_dp["prior"]), float(m_ref["prior"]),
+                               rtol=1e-6)
+    np.testing.assert_allclose(float(m_dp["loss"]), float(m_ref["loss"]),
+                               rtol=1e-5)
+    print("OK dp_tt", float(m_dp["loss"]))
 """
 
 
-@pytest.mark.parametrize("case", ["tp", "cp", "ep", "train", "wire"])
+@pytest.mark.parametrize("case", ["tp", "cp", "ep", "train", "wire",
+                                  "dp_tt"])
 def test_sharded_equivalence(case):
     r = subprocess.run(
         [sys.executable, "-c", SCRIPT % case],
         capture_output=True, text=True, timeout=600,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root",
+             "HOME": os.environ.get("HOME", ""),
              # pin the platform: the forced 8-device host mesh is a CPU
              # construct, and without this a libtpu install spins on TPU
              # metadata discovery inside the cleared env
              "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
-        cwd="/root/repo")
+        cwd=Path(__file__).resolve().parents[1])
     assert r.returncode == 0, f"stdout={r.stdout}\nstderr={r.stderr[-3000:]}"
     assert f"OK" in r.stdout

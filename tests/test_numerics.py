@@ -273,6 +273,9 @@ def test_pallas_fake_quant_multiscale_no_fallback():
     from repro.numerics import pallas_backend as PB
     spec = N.QuantSpec("pow2", 8)
     x = jax.random.normal(jax.random.PRNGKey(12), (4, 6, 8)) * 6
+    # row 0 (scale 2^-3) represents |x| <= 127/8: one element far past it,
+    # so the clipped side of the STE mask is exercised whatever the draw
+    x = x.at[0, 0, 0].set(40.0)
     sc = jnp.asarray([[-3.0], [-1.0], [0.0], [2.0]])            # (L, 1)
     PB.reset_fallback_count()
     fp = N.fake_quant(x, spec, sc, backend="pallas")
@@ -431,24 +434,35 @@ def test_moe_mask_prevents_capacity_theft():
     """Junk (masked) tokens must not displace real tokens from expert
     capacity: with the mask on, the real tokens' outputs are independent
     of the junk tokens' content."""
+    from repro.models.common import apply_site
     from repro.models.moe import make_moe, init_moe, moe_forward
     cfg = ModelConfig(name="m", num_layers=1, d_model=32, num_heads=2,
                       num_kv_heads=2, d_ff=64, vocab_size=64, dtype="float32",
                       # tight capacity (8 slots/expert, 16 tokens wanting
-                      # k=2 experts each) so junk with extreme router
-                      # weights CAN displace real tokens when unmasked
+                      # k=2 experts each) so junk that the router sends to
+                      # one expert with weight 1 fills that expert when
+                      # unmasked
                       moe=MoEConfig(num_experts=2, top_k=2,
                                     capacity_factor=0.5))
     d = make_moe(cfg)
     p = init_moe(jax.random.PRNGKey(0), d, cfg)
     b, s = 1, 16
     x = jax.random.normal(jax.random.PRNGKey(1), (b, s, 32))
-    # half the tokens are "inactive slots" carrying junk
+    # half the tokens are "inactive slots" carrying junk. Junk is built
+    # along the router's expert-0-minus-expert-1 direction, 60 logits past
+    # any real token: the router gives it weight exactly 1.0 (f32) for
+    # expert 0 (junk_a) or expert 1 (junk_b), so unmasked junk takes all 8
+    # slots of that expert from the real tokens — by construction, not by
+    # the draw.
+    def gap(v):
+        lg = apply_site(p["router"], v[None], d.router, cfg)[0]
+        return lg[0] - lg[1]
+
+    u = jax.grad(gap)(jnp.zeros(32))
+    push = (60.0 + jnp.abs(jax.vmap(gap)(x[0])).max()) * u / jnp.dot(u, u)
     mask = jnp.asarray([True] * 8 + [False] * 8)[None]
-    junk_a = x.at[:, 8:].set(100.0 * jax.random.normal(
-        jax.random.PRNGKey(2), (b, 8, 32)))
-    junk_b = x.at[:, 8:].set(50.0 * jax.random.normal(
-        jax.random.PRNGKey(3), (b, 8, 32)))
+    junk_a = x.at[:, 8:].add(push)
+    junk_b = x.at[:, 8:].add(-push)
 
     out_a, _ = moe_forward(p, junk_a, d, cfg, token_mask=mask)
     out_b, _ = moe_forward(p, junk_b, d, cfg, token_mask=mask)
@@ -459,8 +473,9 @@ def test_moe_mask_prevents_capacity_theft():
     # masked tokens contribute nothing (zero combine weight)
     np.testing.assert_allclose(np.asarray(out_a[:, 8:]), 0.0, atol=1e-6)
 
-    # sanity: WITHOUT the mask the big junk steals capacity -> real-token
-    # outputs change with junk content (the pre-fix behavior)
+    # sanity: WITHOUT the mask the junk steals capacity -> real tokens keep
+    # only expert 1 under junk_a and only expert 0 under junk_b (the
+    # pre-fix behavior)
     noma, _ = moe_forward(p, junk_a, d, cfg)
     nomb, _ = moe_forward(p, junk_b, d, cfg)
     assert np.abs(np.asarray(noma[:, :8]) - np.asarray(nomb[:, :8])).max() \

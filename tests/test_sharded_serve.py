@@ -16,6 +16,7 @@ mesh-less reference:
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
@@ -28,6 +29,7 @@ import jax, jax.numpy as jnp, numpy as np
 import repro.configs as C
 from repro.models import build_lm, init_lm
 from repro.serve import Engine, EngineConfig, PoolConfig
+from repro.launch.mesh import make_mesh
 from repro.sharding import ShardPlan, make_plan
 
 CASE = "%s"
@@ -59,12 +61,12 @@ if CASE in ("engine_attn", "engine_jamba"):
         # 8 KV heads on a (1, 8) mesh: one KV head (2 query heads) per device
         cfg, lm, params = setup("internlm2-1.8b", d_model=256, num_heads=16,
                                 num_kv_heads=8, d_ff=160)
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        mesh = make_mesh((1, 8), ("data", "model"))
     else:
         # hybrid: attn KV heads (2) and mamba d_inner (128) shard over
         # model=2; the 4-expert MoE rides the same mesh. All 8 devices used.
         cfg, lm, params = setup("jamba-1.5-large")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
     pcfg = PoolConfig(num_slots=2, page_size=8, pages_per_slot=4,
                       quantized=True)
     prompts = prompts_for(cfg)
@@ -78,7 +80,7 @@ if CASE in ("engine_attn", "engine_jamba"):
 elif CASE == "prefix":
     cfg, lm, params = setup("internlm2-1.8b", d_model=256, num_heads=16,
                             num_kv_heads=8, d_ff=160)
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     # one 20-token base: full-path reuse + two mid-page divergences, so the
     # sharded path must take COW forks on head-sharded pages
     rng = np.random.RandomState(7)
@@ -186,10 +188,10 @@ def test_sharded_serve(case):
         [sys.executable, "-c", SCRIPT % case],
         capture_output=True, text=True, timeout=600,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root",
+             "HOME": os.environ.get("HOME", ""),
              # pin the platform: the forced 8-device mesh is a CPU
              # construct (see test_distributed.py)
              "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
-        cwd="/root/repo")
+        cwd=Path(__file__).resolve().parents[1])
     assert r.returncode == 0, f"stdout={r.stdout}\nstderr={r.stderr[-3000:]}"
     assert "OK" in r.stdout

@@ -15,11 +15,12 @@ import pytest
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 import repro.configs as C
+from repro.launch.mesh import make_mesh
 from repro.models import build_lm, init_lm, lm_forward
 from repro.sharding import ShardPlan, _REPLICATED_LEAVES, _div, make_plan
 
-MESH8 = AbstractMesh((("data", 1), ("model", 8)))
-DP8 = AbstractMesh((("data", 8),))
+MESH8 = AbstractMesh((1, 8), ("data", "model"))
+DP8 = AbstractMesh((8,), ("data",))
 
 
 # ---------------------------------------------------------------------------
@@ -61,7 +62,7 @@ def test_dp_only_mesh_forward_matches_meshless():
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                               cfg.vocab_size)
     ref, _, _ = lm_forward(params, lm, ShardPlan(mesh=None), tokens=toks)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     out, _, _ = jax.jit(
         lambda p, t: lm_forward(p, lm, make_plan(mesh, "tp"), tokens=t))(
             params, toks)

@@ -22,16 +22,7 @@ def _train_mlp(prior: bool, quantize: bool, steps: int = 250,
     xs, ys = fashion_like(batch * 64, seed=1)
     xq, yq = fashion_like(512, seed=2)
 
-    @jax.jit
-    def step(params, opt, batch):
-        loss, grads = jax.value_and_grad(
-            MLP.mlp_loss, allow_int=True)(params, batch, d)
-        params, opt = A.adam_update(params, grads, opt, jnp.asarray(lr), tcfg)
-        if d.tt.rank_adapt:
-            params = MLP.mlp_lambda_update(params, d)
-        if d.qc.enable:
-            params = MLP.mlp_scale_update(params, batch, grads, d)
-        return params, opt, loss
+    step = jax.jit(MLP.mlp_train_step(d, tcfg))
 
     losses = []
     for i in range(steps):

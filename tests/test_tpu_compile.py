@@ -1,0 +1,106 @@
+"""Compile rehearsal for a TPU v5e that is described, not attached.
+
+The main-path Pallas kernels are compiled for one chip of a ``v5e:2x2``
+topology at the serve smoke's widths (internlm2-1.8b: 8 slots, 16 query /
+8 KV heads, head dim 128, pages of 16): the fused paged-attention kernel,
+the KV pool's row-scale codec, and PE1–PE3. Each compiled program must
+contain the kernel (``tpu_custom_call``). Nothing runs: this catches what
+the TPU compiler refuses (unsupported layouts, block shapes, VMEM use)
+without chip time.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and the fixture skips
+where it cannot be described. The codec and PE wrappers choose interpret
+mode from ``jax.default_backend()`` (the CPU here), so the tests switch the
+names those wrappers call to compiled mode.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.kernels import paged_attention as PA
+from repro.numerics import QuantSpec, get_codec, pallas_backend
+
+B, HQ, HKV, DH, PAGE, PP = 8, 16, 8, 128, 16, 19
+POOL_PAGES = B * PP + 1
+LAYERS = 24
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "can't describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_mode(monkeypatch):
+    monkeypatch.setattr(pallas_backend, "_interpret", lambda: False)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.int8, jnp.bfloat16])
+@pytest.mark.parametrize("q_rows", [1, 5])
+def test_paged_attention_kernel(one_chip, q_rows, dtype):
+    kern = functools.partial(PA.paged_attention_kernel, page_size=PAGE,
+                             quantized=dtype == jnp.int8, interpret=False)
+    page = ((POOL_PAGES, PAGE, HKV, DH), dtype)
+    _compile(kern, one_chip, ((B, q_rows, HQ, DH), jnp.bfloat16), page, page,
+             ((B,), jnp.float32), ((B,), jnp.float32), ((B, PP), jnp.int32),
+             ((B,), jnp.int32))
+
+
+SPEC = QuantSpec("pow2", 8, 0, "int8", "per_tensor_max")
+
+
+@pytest.mark.parametrize("site,shape,scale", [
+    # decode append: one token per slot, one scale per slot
+    ("append", (B, HKV, DH), (B, 1, 1)),
+    # whole-prompt prefill write: every layer, one scale per layer
+    ("prefill", (LAYERS, 256, HKV, DH), (LAYERS, 1)),
+])
+def test_kv_codec_encode(one_chip, compiled_mode, site, shape, scale):
+    codec = get_codec(SPEC, "pallas")
+    _compile(lambda x, s: codec.encode(x, SPEC, s).codes, one_chip,
+             (shape, jnp.bfloat16), (scale, jnp.float32))
+
+
+def test_kv_codec_decode(one_chip, compiled_mode):
+    """The gather path's dequantize: a (B, max_len, Hkv, Dh) slot view."""
+    from repro.numerics import QTensor
+    codec = get_codec(SPEC, "pallas")
+    _compile(lambda q, s: codec.decode(QTensor(q, s, SPEC), jnp.bfloat16),
+             one_chip, ((B, PP * PAGE, HKV, DH), jnp.int8),
+             ((B, 1, 1, 1), jnp.float32))
+
+
+@pytest.mark.parametrize("bits", [None, 8])
+def test_pe1(one_chip, compiled_mode, bits):
+    _compile(lambda z, g: ops.pe1(z, g, bits=bits), one_chip,
+             ((128, 16, 16), jnp.float32), ((16, 32, 16), jnp.float32))
+
+
+def test_pe2(one_chip, compiled_mode):
+    _compile(ops.pe2, one_chip, ((64, 16, 16), jnp.float32),
+             ((16, 32), jnp.float32))
+
+
+def test_pe3(one_chip, compiled_mode):
+    _compile(ops.pe3, one_chip, ((256, 64), jnp.float32),
+             ((256, 128), jnp.float32))
