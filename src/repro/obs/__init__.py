@@ -7,7 +7,8 @@ One API for every layer of the stack:
   (clip/saturation fractions, scale drift) that bit-agree across codec
   backends.
 - ``trace``: host-side ring-buffered ``TraceRecorder`` — engine/scheduler/
-  train-driver structured events, zero device overhead.
+  train-driver structured events, zero device overhead; and ``span`` /
+  ``step_span``, the engine's phases in the JAX profiler's trace.
 - ``ledger``: byte-accurate live ``MemoryLedger`` — every allocation site
   (params, moments, residuals, KV/state pools, prefix pages) reports in;
   per-phase peak watermarks, ``jax.live_arrays()`` reconcile, live
@@ -24,13 +25,13 @@ from .export import (chrome_trace, read_jsonl, write_chrome_trace,
                      write_jsonl)
 from .ledger import PHASES, MemoryLedger, device_breakdown
 from .spans import Span, check_nesting, request_spans
-from .trace import Event, TraceRecorder
+from .trace import Event, TraceRecorder, span, step_span
 
 __all__ = [
     "CounterRegistry", "registry", "record_kernel_call", "kernel_costs",
     "pow2_clip_stats", "saturation_counts", "scale_drift_stats",
     "tree_sat_stats", "fraction",
-    "Event", "TraceRecorder",
+    "Event", "TraceRecorder", "span", "step_span",
     "MemoryLedger", "device_breakdown", "PHASES",
     "Span", "request_spans", "check_nesting",
     "write_jsonl", "read_jsonl", "chrome_trace", "write_chrome_trace",

@@ -36,6 +36,45 @@ kind                  fields
 ``state_restore``     slot, nbytes
 ``train_step``        step, loss, dur (train driver loop)
 ====================  =====================================================
+
+Profiler spans
+--------------
+
+Beside the events, the serving engine marks its step phases in the JAX
+profiler's own trace with ``span(name, **fields)`` (a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``) and
+``step_span(n)`` (a ``StepTraceAnnotation``). They are always on, with or
+without a recorder: the profiler decides whether they are recorded, and a
+span costs about a microsecond while it is off. In a trace they sit on the
+host plane, on the same clock as the device's operations, with their
+fields as event stats. Spans are opened only in host code, never inside a
+jitted function, per slot or per token. Fields are JSON scalars.
+
+==============================  =======================  ====================
+span                            parent                   fields
+==============================  =======================  ====================
+``repro.engine.step``           the caller's span        step_num
+``repro.engine.admit``          ``repro.engine.step``
+``repro.engine.prefill``        ``repro.engine.step``    rid, tokens,
+                                                         computed, padded
+``repro.engine.pages``          ``repro.engine.step``
+``repro.engine.dispatch``       ``repro.engine.step``    rows
+``repro.engine.sync``           ``repro.engine.step``
+``repro.engine.bookkeeping``    ``repro.engine.step``
+==============================  =======================  ====================
+
+``step`` is one ``Engine.step()``; ``step_num`` counts the decode steps
+before it. Its children come in the order above and together cover it;
+``admit``, ``prefill`` and ``bookkeeping`` may occur several times or not
+at all. ``admit``: one ``try_admit`` and its bookkeeping. ``prefill``: one
+request's prefill, whole or chunked or after a prefix hit; ``tokens`` is
+the prompt's length, ``computed`` the tokens run after a prefix hit,
+``padded`` the tokens run with bucket padding. ``pages``: mapping the
+pages the step writes, and preemption. ``dispatch``: the decode inputs'
+transfer to the device and the step's enqueue, over ``rows`` active slots.
+``sync``: the sample's enqueue and the host's wait for its tokens.
+``bookkeeping``: tokens, retirement, ``ServeMetrics``, the memory ledger,
+recorder events and the health read.
 """
 from __future__ import annotations
 
@@ -43,6 +82,8 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterator
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
 @dataclass(frozen=True)
@@ -96,3 +137,15 @@ class TraceRecorder:
     def clear(self) -> None:
         self._ring.clear()
         self.dropped = 0
+
+
+def span(name: str, **fields: Any) -> TraceAnnotation:
+    """A profiler span ``repro.<name>`` with JSON-scalar ``fields``; use as a
+    context manager in host code (see "Profiler spans" above)."""
+    return TraceAnnotation(f"repro.{name}", **fields)
+
+
+def step_span(n: int) -> StepTraceAnnotation:
+    """The span around one ``Engine.step()``: ``repro.engine.step`` with
+    ``step_num`` ``n``."""
+    return StepTraceAnnotation("repro.engine.step", step_num=n)

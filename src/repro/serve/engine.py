@@ -52,6 +52,7 @@ from ..models import attention as A
 from ..models import ssm as S
 from ..models.common import apply_site, rms_norm
 from ..models.lm import LMDef, embed_tokens, lm_forward, sub_ffn_decode
+from ..obs.trace import span, step_span
 from ..sharding import ShardPlan
 from . import kv_cache as KC
 from . import state_cache as SC
@@ -805,60 +806,64 @@ class Engine:
         accept/replace decision deterministic)."""
         sched = self.sched
         k = self.ecfg.spec_k
-        table = jnp.asarray(sched.page_table)
-        lens = jnp.asarray(sched.lens_vector())
-        active = jnp.asarray(sched.active_mask())
-        tokens = jnp.asarray(sched.tokens_vector())
-        sp = [sched.slots[s].req.sampling if sched.slots[s]
-              else SamplingParams() for s in range(self.pcfg.num_slots)]
-        temp = jnp.asarray([p.temperature for p in sp], jnp.float32)
-        topk = jnp.asarray([p.top_k for p in sp], jnp.int32)
-        topp = jnp.asarray([p.top_p for p in sp], jnp.float32)
-        dkey = jax.random.fold_in(self._key, self._nsample)
-        self._nsample += 1
-        akey = jax.random.fold_in(self._key, self._nsample)
-        self._nsample += 1
-        t0 = self.trace.clock() if self.trace is not None else 0.0
-        dtoks, dprobs, self._draft_pool = self._draft_propose_jit(
-            self._draft_params, self._draft_pool, self._draft_table, lens,
-            active, tokens, dkey, temp, topk, topp)
-        blk = jnp.concatenate([tokens, dtoks], axis=1)       # (B, k+1)
-        vlogits, self.pool = self._verify_jit(self.params, self.pool,
-                                              table, lens, active, blk)
-        acc_len, next_tok = self._accept_jit(vlogits, dprobs, dtoks, akey,
-                                             temp, topk, topp)
-        acc = np.asarray(acc_len)
-        nxt = np.asarray(next_tok)
-        dt = np.asarray(dtoks)
-        dur = (self.trace.clock() - t0) if self.trace is not None else None
-        accepted = emitted = 0
-        for slot in active_slots:
-            st = sched.slots[slot]
-            a = int(acc[slot])
-            accepted += a
-            # eos / max_new truncate the emission mid-prefix: tokens past
-            # the stop never leave the engine (their K/V junk sits above
-            # the slot's final length and the slot retires anyway)
-            for tok in [int(t) for t in dt[slot, :a]] + [int(nxt[slot])]:
-                st.generated.append(tok)
-                st.last_token = tok
-                emitted += 1
+        with span("engine.dispatch", rows=len(active_slots)):
+            table = jnp.asarray(sched.page_table)
+            lens = jnp.asarray(sched.lens_vector())
+            active = jnp.asarray(sched.active_mask())
+            tokens = jnp.asarray(sched.tokens_vector())
+            sp = [sched.slots[s].req.sampling if sched.slots[s]
+                  else SamplingParams() for s in range(self.pcfg.num_slots)]
+            temp = jnp.asarray([p.temperature for p in sp], jnp.float32)
+            topk = jnp.asarray([p.top_k for p in sp], jnp.int32)
+            topp = jnp.asarray([p.top_p for p in sp], jnp.float32)
+            dkey = jax.random.fold_in(self._key, self._nsample)
+            self._nsample += 1
+            akey = jax.random.fold_in(self._key, self._nsample)
+            self._nsample += 1
+            t0 = self.trace.clock() if self.trace is not None else 0.0
+            dtoks, dprobs, self._draft_pool = self._draft_propose_jit(
+                self._draft_params, self._draft_pool, self._draft_table,
+                lens, active, tokens, dkey, temp, topk, topp)
+            blk = jnp.concatenate([tokens, dtoks], axis=1)   # (B, k+1)
+            vlogits, self.pool = self._verify_jit(self.params, self.pool,
+                                                  table, lens, active, blk)
+        with span("engine.sync"):
+            acc_len, next_tok = self._accept_jit(vlogits, dprobs, dtoks,
+                                                 akey, temp, topk, topp)
+            acc = np.asarray(acc_len)
+            nxt = np.asarray(next_tok)
+            dt = np.asarray(dtoks)
+        with span("engine.bookkeeping"):
+            dur = (self.trace.clock() - t0) if self.trace is not None \
+                else None
+            accepted = emitted = 0
+            for slot in active_slots:
+                st = sched.slots[slot]
+                a = int(acc[slot])
+                accepted += a
+                # eos / max_new truncate the emission mid-prefix: tokens past
+                # the stop never leave the engine (their K/V junk sits above
+                # the slot's final length and the slot retires anyway)
+                for tok in [int(t) for t in dt[slot, :a]] + [int(nxt[slot])]:
+                    st.generated.append(tok)
+                    st.last_token = tok
+                    emitted += 1
+                    if st.done():
+                        break
+                sched.trim_unused(slot)
                 if st.done():
-                    break
-            sched.trim_unused(slot)
-            if st.done():
-                self._finish(slot)
-        free_pages = sched.alloc.free_pages if sched.paged else None
-        self.metrics.decode_step(emitted, free_pages=free_pages, dur=dur)
-        self.metrics.spec_step(len(active_slots), k * len(active_slots),
-                               accepted, emitted)
-        self._ledger_update("decode")
-        if self.trace is not None:
-            self.trace.emit("spec_step", step=self.metrics.decode_steps,
-                            n_active=len(active_slots),
-                            proposed=k * len(active_slots),
-                            accepted=accepted, emitted=emitted,
-                            free_pages=free_pages, dur=dur)
+                    self._finish(slot)
+            free_pages = sched.alloc.free_pages if sched.paged else None
+            self.metrics.decode_step(emitted, free_pages=free_pages, dur=dur)
+            self.metrics.spec_step(len(active_slots), k * len(active_slots),
+                                   accepted, emitted)
+            self._ledger_update("decode")
+            if self.trace is not None:
+                self.trace.emit("spec_step", step=self.metrics.decode_steps,
+                                n_active=len(active_slots),
+                                proposed=k * len(active_slots),
+                                accepted=accepted, emitted=emitted,
+                                free_pages=free_pages, dur=dur)
 
     # ---- memory ledger -------------------------------------------------
     def _ledger_update(self, phase: str | None = None) -> None:
@@ -929,7 +934,31 @@ class Engine:
             jnp.asarray([p.top_p for p in sp], jnp.float32))
         return np.asarray(toks)
 
-    def _do_prefill(self, slot: int, st) -> None:
+    def _prefill_plan(self, st) -> list[tuple[int, int, int]]:
+        """(start, end, width) of each prefill chunk the prompt runs as:
+        the whole prompt, its chunks, or the suffix after a prefix hit;
+        ``width`` is the tokens the chunk runs, padding included."""
+        plen, resume = st.prompt_len, st.prefix_len
+        if resume > 0:
+            c = self.ecfg.prefill_chunk
+            chunks = ([(s, min(s + c, plen)) for s in range(resume, plen, c)]
+                      if c > 0 else [(resume, plen)])
+        else:
+            chunks = self.sched.prefill_chunks(plen)
+        out = []
+        for ci, (c0, c1) in enumerate(chunks):
+            n = c1 - c0
+            if self._state_keys:
+                # stateful archs run exact-length (see module docstring)
+                width = n
+            elif (ci == 0 and c0 == 0) or self.ecfg.prefill_chunk <= 0:
+                width = bucket_len(n, self.ecfg.prefill_bucket)
+            else:
+                width = self.ecfg.prefill_chunk
+            out.append((c0, c1, width))
+        return out
+
+    def _do_prefill(self, slot: int, st, plan) -> None:
         plen = st.prompt_len
         t0 = self.trace.clock() if self.trace is not None else 0.0
         self._ledger_update("prefill")
@@ -971,15 +1000,11 @@ class Engine:
             if self.trace is not None:
                 self.trace.emit("cache_hit", rid=st.req.rid, slot=slot,
                                 hit_tokens=resume, prompt_len=plen)
-            c = self.ecfg.prefill_chunk
-            chunks = ([(s, min(s + c, plen)) for s in range(resume, plen, c)]
-                      if c > 0 else [(resume, plen)])
-        else:
-            chunks = self.sched.prefill_chunks(plen)
         last_logits = None
-        for ci, (c0, c1) in enumerate(chunks):
+        for ci, (c0, c1, width) in enumerate(plan):
             toks = st.req.prompt[c0:c1]
-            if self.trace is not None and len(chunks) > 1:
+            padded = toks + [0] * (width - len(toks))
+            if self.trace is not None and len(plan) > 1:
                 self.trace.emit("prefill_chunk", rid=st.req.rid, slot=slot,
                                 start=c0, len=c1 - c0)
             if ci == 0 and c0 == 0:
@@ -989,9 +1014,6 @@ class Engine:
                 # scan-carried state; see module docstring) — bucket
                 # padding applies to attention-only archs, masked out of
                 # MoE capacity via lm_forward's token_mask.
-                bucket = 0 if stateful else self.ecfg.prefill_bucket
-                padded = toks + [0] * (bucket_len(len(toks), bucket)
-                                       - len(toks))
                 tok_arr = jnp.asarray(padded, jnp.int32)[None]
                 last_logits, cache = self._prefill_fns.get(
                     (len(padded), cap))(self.params, tok_arr,
@@ -1010,12 +1032,6 @@ class Engine:
                 # hit — go through the chunked step, padded to a stable
                 # width (the chunk size, or the bucketed suffix length when
                 # chunking is off) so compiled shapes stay bounded
-                if self.ecfg.prefill_chunk > 0:
-                    width = self.ecfg.prefill_chunk
-                else:
-                    width = bucket_len(len(toks), self.ecfg.prefill_bucket)
-                pad = 0 if stateful else (width - len(toks))
-                padded = toks + [0] * pad
                 tok_arr = jnp.asarray(padded, jnp.int32)[None]
                 last_logits, self.pool, self.spool = self._chunk_fns.get(
                     (len(padded), cap))(
@@ -1060,20 +1076,32 @@ class Engine:
 
     # ---- engine iteration ---------------------------------------------
     def step(self) -> None:
-        """One engine iteration: admit + prefill, then one batched decode."""
+        """One engine iteration: admit + prefill, then one batched decode.
+        The iteration and its phases are spans in the profiler's trace
+        (``repro.engine.*``; schema in ``repro.obs.trace``)."""
+        with step_span(self.metrics.decode_steps):
+            self._step()
+
+    def _step(self) -> None:
         sched = self.sched
         while True:
-            adm = sched.try_admit()
-            if adm is None:
-                break
-            slot, st = adm
-            self.metrics.request_admitted(st.req.rid, st.prompt_len)
-            if self.trace is not None:
-                self.trace.emit("admit", rid=st.req.rid, slot=slot,
-                                pages=len(sched.slot_pages[slot]))
-            self._do_prefill(slot, st)
+            with span("engine.admit"):
+                adm = sched.try_admit()
+                if adm is None:
+                    break
+                slot, st = adm
+                self.metrics.request_admitted(st.req.rid, st.prompt_len)
+                if self.trace is not None:
+                    self.trace.emit("admit", rid=st.req.rid, slot=slot,
+                                    pages=len(sched.slot_pages[slot]))
+            plan = self._prefill_plan(st)
+            with span("engine.prefill", rid=st.req.rid, tokens=st.prompt_len,
+                      computed=st.prompt_len - st.prefix_len,
+                      padded=sum(w for _, _, w in plan)):
+                self._do_prefill(slot, st, plan)
             if st.done():
-                self._finish(slot)
+                with span("engine.bookkeeping"):
+                    self._finish(slot)
 
         active_slots = [i for i, s in enumerate(sched.slots) if s is not None]
         if not active_slots:
@@ -1081,26 +1109,28 @@ class Engine:
         # lazily map the page(s) each active slot is about to write — one
         # for plain decode, the k+1 verify span for speculative decoding;
         # preempt the youngest slot if the pool is exhausted
-        span = self.ecfg.spec_k + 1 if self._spec else 1
-        for slot in list(active_slots):
-            if sched.slots[slot] is None:
-                continue
-            while not (sched.ensure_page(slot) if span == 1
-                       else sched.ensure_span(slot, span)):
-                # capture the victim before retire clears its slot state
-                yst = (sched.slots[sched.admission_order[-1]]
-                       if len(sched.admission_order) > 1 else None)
-                evicted = sched.preempt_youngest()
-                if evicted is None:
-                    raise RuntimeError(
-                        "KV pool exhausted and nothing to preempt — "
-                        "increase num_pages/pages_per_slot")
-                self.metrics.preempted()
-                if self.trace is not None:
-                    self.trace.emit("preempt", rid=yst.req.rid, slot=evicted,
-                                    gen_len=len(yst.generated))
-                if evicted == slot:
-                    break
+        need = self.ecfg.spec_k + 1 if self._spec else 1
+        with span("engine.pages"):
+            for slot in list(active_slots):
+                if sched.slots[slot] is None:
+                    continue
+                while not (sched.ensure_page(slot) if need == 1
+                           else sched.ensure_span(slot, need)):
+                    # capture the victim before retire clears its slot state
+                    yst = (sched.slots[sched.admission_order[-1]]
+                           if len(sched.admission_order) > 1 else None)
+                    evicted = sched.preempt_youngest()
+                    if evicted is None:
+                        raise RuntimeError(
+                            "KV pool exhausted and nothing to preempt — "
+                            "increase num_pages/pages_per_slot")
+                    self.metrics.preempted()
+                    if self.trace is not None:
+                        self.trace.emit("preempt", rid=yst.req.rid,
+                                        slot=evicted,
+                                        gen_len=len(yst.generated))
+                    if evicted == slot:
+                        break
         active_slots = [i for i, s in enumerate(sched.slots) if s is not None]
         if not active_slots:
             return
@@ -1108,48 +1138,52 @@ class Engine:
             self._spec_step(active_slots)
             return
 
-        table = jnp.asarray(sched.page_table)
-        lens = jnp.asarray(sched.lens_vector())
-        active = jnp.asarray(sched.active_mask())
-        tokens = jnp.asarray(sched.tokens_vector())
-        t0 = self.trace.clock() if self.trace is not None else 0.0
-        health = None
-        if self._health:
-            logits, self.pool, self.spool, health = self._decode_jit(
-                self.params, self.pool, self.spool, table, lens, active,
-                tokens)
-        else:
-            logits, self.pool, self.spool = self._decode_jit(
-                self.params, self.pool, self.spool, table, lens, active,
-                tokens)
-        toks = self._sample(logits, list(range(self.pcfg.num_slots)))
-        dur = (self.trace.clock() - t0) if self.trace is not None else None
-        free_pages = sched.alloc.free_pages if sched.paged else None
-        for slot in active_slots:
-            st = sched.slots[slot]
-            tok = int(toks[slot])
-            st.generated.append(tok)
-            st.last_token = tok
-            if st.done():
-                self._finish(slot)
-        self.metrics.decode_step(len(active_slots), free_pages=free_pages,
-                                 dur=dur)
-        self._ledger_update("decode")
-        if self.trace is not None:
-            self.trace.emit("decode_step", step=self.metrics.decode_steps,
-                            n_active=len(active_slots),
-                            free_pages=free_pages, dur=dur)
-        if health is not None:
-            if self._health_kv:
-                self.metrics.record_health(
-                    "kv_cache", int(health["kv_clipped"]),
-                    int(health["kv_total"]))
-            if self._health_state:
-                self.metrics.record_health(
-                    "ssm_state", int(health["state_clipped"]),
-                    int(health["state_total"]),
-                    float(health["state_drift_sum"]),
-                    float(health["state_drift_n"]))
+        with span("engine.dispatch", rows=len(active_slots)):
+            table = jnp.asarray(sched.page_table)
+            lens = jnp.asarray(sched.lens_vector())
+            active = jnp.asarray(sched.active_mask())
+            tokens = jnp.asarray(sched.tokens_vector())
+            t0 = self.trace.clock() if self.trace is not None else 0.0
+            health = None
+            if self._health:
+                logits, self.pool, self.spool, health = self._decode_jit(
+                    self.params, self.pool, self.spool, table, lens, active,
+                    tokens)
+            else:
+                logits, self.pool, self.spool = self._decode_jit(
+                    self.params, self.pool, self.spool, table, lens, active,
+                    tokens)
+        with span("engine.sync"):
+            toks = self._sample(logits, list(range(self.pcfg.num_slots)))
+        with span("engine.bookkeeping"):
+            dur = (self.trace.clock() - t0) if self.trace is not None \
+                else None
+            free_pages = sched.alloc.free_pages if sched.paged else None
+            for slot in active_slots:
+                st = sched.slots[slot]
+                tok = int(toks[slot])
+                st.generated.append(tok)
+                st.last_token = tok
+                if st.done():
+                    self._finish(slot)
+            self.metrics.decode_step(len(active_slots),
+                                     free_pages=free_pages, dur=dur)
+            self._ledger_update("decode")
+            if self.trace is not None:
+                self.trace.emit("decode_step", step=self.metrics.decode_steps,
+                                n_active=len(active_slots),
+                                free_pages=free_pages, dur=dur)
+            if health is not None:
+                if self._health_kv:
+                    self.metrics.record_health(
+                        "kv_cache", int(health["kv_clipped"]),
+                        int(health["kv_total"]))
+                if self._health_state:
+                    self.metrics.record_health(
+                        "ssm_state", int(health["state_clipped"]),
+                        int(health["state_total"]),
+                        float(health["state_drift_sum"]),
+                        float(health["state_drift_n"]))
 
     def run(self) -> dict[int, Completion]:
         """Drive until every submitted request has completed."""

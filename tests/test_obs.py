@@ -379,3 +379,64 @@ def test_engine_emits_trace_and_kv_health():
     kv = eng.summary()["quant_health"]["kv_cache"]
     assert kv["total"] > 0
     assert 0.0 <= kv["clip_fraction"] < 0.5
+
+
+def _profiler_spans(logdir) -> list[tuple]:
+    """(name, start_ns, end_ns, stats) of the ``repro.*`` events on the host
+    plane of the profiler trace written under ``logdir``, in start order."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+    pb, = Path(logdir).glob("plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            out.extend((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                        dict(ev.stats)) for ev in line.events
+                       if ev.name.startswith("repro.engine."))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def test_engine_step_phases_in_profiler_trace(tmp_path):
+    cfg, lm, params = _serve_setup()
+    pcfg = PoolConfig(num_slots=2, page_size=8, pages_per_slot=4,
+                      quantized=True)
+    eng = Engine(lm, params, EngineConfig(pool=pcfg, prefill_bucket=8), PLAN)
+    rng = np.random.RandomState(0)
+    lens = [5, 11, 3]
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist() for n in lens]
+    for p in prompts:                   # compile every shape untraced
+        eng.submit(p, max_new_tokens=3)
+    eng.run()
+    for p in prompts:
+        eng.submit(p, max_new_tokens=3)
+    steps = 0
+    with jax.profiler.trace(str(tmp_path)):
+        while eng.sched.has_work():
+            eng.step()
+            steps += 1
+    spans = _profiler_spans(tmp_path)
+    outer = [s for s in spans if s[0] == "repro.engine.step"]
+    assert len(outer) == steps
+    nums = [s[3]["step_num"] for s in outer]
+    assert nums == sorted(nums) and nums[-1] < eng.metrics.decode_steps
+    phases = [s for s in spans if s[0] != "repro.engine.step"]
+    assert {s[0] for s in phases} == {
+        "repro.engine.admit", "repro.engine.prefill", "repro.engine.pages",
+        "repro.engine.dispatch", "repro.engine.sync",
+        "repro.engine.bookkeeping"}
+    # each phase lies inside exactly one step; a step's phases follow one
+    # another without overlap
+    for st in outer:
+        kids = [s for s in phases if st[1] <= s[1] and s[2] <= st[2]]
+        assert kids and all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+    assert sum(1 for s in phases for st in outer
+               if st[1] <= s[1] and s[2] <= st[2]) == len(phases)
+    pre = [s[3] for s in phases if s[0] == "repro.engine.prefill"]
+    assert sorted(f["tokens"] for f in pre) == sorted(lens)
+    assert all(f["computed"] == f["tokens"]
+               and f["padded"] == -(-f["tokens"] // 8) * 8 for f in pre)
+    assert {s[3]["rows"] for s in phases
+            if s[0] == "repro.engine.dispatch"} <= {1, 2}
