@@ -140,7 +140,8 @@ def quantize_fused(x: jax.Array, step_log2: jax.Array, bits: int) -> jax.Array:
 
 
 def _paged_attention_dispatch(q, kdata, vdata, kscale, vscale, table, lens,
-                              *, page_size, quantized, impl, page_chunk):
+                              layer=None, *, page_size, quantized, impl,
+                              page_chunk):
     """impl-resolved page walk on whatever head slice it is handed — the
     whole pool, or one device's head shard under ``shard_map``."""
     if impl == "pallas":
@@ -148,7 +149,7 @@ def _paged_attention_dispatch(q, kdata, vdata, kscale, vscale, table, lens,
             return PA.paged_attention_kernel(
                 q, kdata, vdata, kscale, vscale, table, lens,
                 page_size=page_size, quantized=quantized,
-                interpret=_interpret())
+                interpret=_interpret(), layer=layer)
     if impl == "jnp":
         if page_chunk is None:
             page_chunk = max(1, 256 // page_size)
@@ -156,7 +157,7 @@ def _paged_attention_dispatch(q, kdata, vdata, kscale, vscale, table, lens,
             return PA.paged_attention_jnp(
                 q, kdata, vdata, kscale, vscale, table, lens,
                 page_size=page_size, quantized=quantized,
-                page_chunk=page_chunk)
+                page_chunk=page_chunk, layer=layer)
     raise ValueError(f"unknown paged_attention impl {impl!r}")
 
 
@@ -164,12 +165,14 @@ def paged_attention(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
                     kscale: jax.Array, vscale: jax.Array, table: jax.Array,
                     lens: jax.Array, *, page_size: int, quantized: bool,
                     impl: str = "auto", page_chunk: int | None = None,
-                    plan=None) -> jax.Array:
+                    plan=None, layer: jax.Array | None = None) -> jax.Array:
     """Fused paged attention: per-page int8 dequant + online-softmax
     attention over each slot's page list (never materializes the fp32 slot
     view). q is (B, Hq, Dh) for single-token decode or (B, S, Hq, Dh) for a
     q-block (chunked prefill / k-token speculative verify); ``lens`` is the
-    position of the first query row either way. See
+    position of the first query row either way. k/v are one layer's
+    (P+1, page, Hkv, Dh) pages, or, with ``layer`` given, the stacked
+    (L, P+1, page, Hkv, Dh) pool leaf the walk indexes at that layer. See
     ``kernels/paged_attention.py`` for layouts.
 
     impl: "pallas" (the kernel; compiled on TPU, interpret elsewhere),
@@ -197,7 +200,8 @@ def paged_attention(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
     # an operand, but only each slot's mapped pages move — model the table-
     # addressable footprint (B * pages_per_slot pages) plus q in and out
     pages_touched = table.shape[0] * table.shape[1]
-    page_bytes = (int(np.prod(kdata.shape[1:])) + int(np.prod(vdata.shape[1:]))
+    page_bytes = (int(np.prod(kdata.shape[-3:]))
+                  + int(np.prod(vdata.shape[-3:]))
                   ) * jnp.dtype(kdata.dtype).itemsize
     record_kernel_call(f"paged_attention.{impl}",
                        bytes_moved=pages_touched * page_bytes
@@ -205,7 +209,8 @@ def paged_attention(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
     f = functools.partial(_paged_attention_dispatch, page_size=page_size,
                           quantized=quantized, impl=impl,
                           page_chunk=page_chunk)
-    hkv = kdata.shape[2]
+    extra = () if layer is None else (layer,)
+    hkv = kdata.shape[-2]
     if plan is not None and plan.shards_kv_heads(hkv) \
             and q.shape[-2] % hkv == 0:
         from jax.sharding import PartitionSpec as P
@@ -215,15 +220,15 @@ def paged_attention(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
         # (B, S, Hq, Dh) q-block
         qspec = (P(None, "model", None) if q.ndim == 3
                  else P(None, None, "model", None))
-        f = shard_map(
-            f, plan.mesh,
-            in_specs=(qspec,                           # q
-                      P(None, None, "model", None),    # k pages
-                      P(None, None, "model", None),    # v pages
-                      P(None), P(None),                # per-slot scales
-                      P(None, None), P(None)),         # table, lens
-            out_specs=qspec)
-    return f(q, kdata, vdata, kscale, vscale, table, lens)
+        # pages: (P+1, page, Hkv, Dh), or the stacked pool with a leading
+        # layer axis; the layer index is replicated
+        pspec = P(*([None] * (kdata.ndim - 2)), "model", None)
+        specs = (qspec, pspec, pspec,                  # q, k/v pages
+                 P(None), P(None),                     # per-slot scales
+                 P(None, None), P(None))               # table, lens
+        f = shard_map(f, plan.mesh, in_specs=specs + (P(),) * len(extra),
+                      out_specs=qspec)
+    return f(q, kdata, vdata, kscale, vscale, table, lens, *extra)
 
 
 def ttm_matvec_kernels(cores, x, spec):

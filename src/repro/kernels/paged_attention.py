@@ -60,7 +60,18 @@ Layouts (one attention sublayer, one layer of the scanned stack):
             (B, Hq, Dh) q is accepted as the S=1 decode case and the
             result is returned rank-3 to match
 - k/v data: (P+1, page, Hkv, Dh) int8 codes (quantized pool) or fp values;
-            row ``P`` is the trash page absorbing inactive-slot writes
+            row ``P`` is the trash page absorbing inactive-slot writes.
+            Or the stacked pool leaf (L, P+1, page, Hkv, Dh) with a
+            ``layer`` scalar: the walk reads layer ``layer``'s pages
+            straight out of the stack, so the caller never slices a
+            per-layer slab. The decode step carries the whole pool through
+            its layer scan and updates it in place; slicing a slab per
+            layer for the kernel's operand (or scanning the pool as xs/ys)
+            would copy the pool every step
+- layer:    () int32, the stacked form only. The Pallas kernel takes it as
+            a third scalar-prefetch operand and its page index map becomes
+            ``(layer, tab[b, p], 0, 0, 0)`` with the layer dim squeezed,
+            so the body sees the same (1, page, Hkv, Dh) page block
 - scale:    (B,) f32 per-slot ``scale_log2`` (pow-2 grid, kv_cache site)
 - table:    (B, pages_per_slot) int32 physical page ids (trash when unmapped)
 - lens:     (B,) int32 position of the FIRST query row (row j attends keys
@@ -201,37 +212,55 @@ def _pa_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                     ).astype(o_ref.dtype)
 
 
+def _pa_kernel_layered(tab_ref, lens_ref, layer_ref, *refs, **kw):
+    """``_pa_kernel`` for the stacked pool: the layer scalar is consumed by
+    the page BlockSpec's index map, so the body never reads it."""
+    del layer_ref
+    _pa_kernel(tab_ref, lens_ref, *refs, **kw)
+
+
 def paged_attention_kernel(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
                            kscale: jax.Array, vscale: jax.Array,
                            table: jax.Array, lens: jax.Array, *,
                            page_size: int, quantized: bool,
-                           interpret: bool = False) -> jax.Array:
-    """Fused paged attention via Pallas. Shapes per module docstring;
-    returns (B, S, Hq, Dh) in q.dtype ((B, Hq, Dh) for rank-3 q)."""
+                           interpret: bool = False,
+                           layer: jax.Array | None = None) -> jax.Array:
+    """Fused paged attention via Pallas. Shapes per module docstring
+    (k/v per layer, or stacked with ``layer``); returns (B, S, Hq, Dh) in
+    q.dtype ((B, Hq, Dh) for rank-3 q)."""
     q, squeeze = _norm_q(q)
     b, sq, hq, dh = q.shape
     pp = table.shape[1]
-    hkv = kdata.shape[2]
+    hkv = kdata.shape[-2]
     assert hq % hkv == 0, (hq, hkv)
     cols = sq * (hq // hkv)
+    # the page-pointer chase: block (pi of slot bi) is physical page
+    # tab[bi, pi] — unmapped entries point at the trash page, whose
+    # positions all sit above lens[bi] and mask to NEG_INF
+    if layer is None:
+        prefetch = (table, lens)
+        page_spec = pl.BlockSpec((1, page_size, hkv, dh),
+                                 lambda bi, pi, tab, ln: (tab[bi, pi], 0, 0, 0))
+        body = _pa_kernel
+    else:
+        prefetch = (table, lens, jnp.reshape(layer, (1,)).astype(jnp.int32))
+        page_spec = pl.BlockSpec(
+            (None, 1, page_size, hkv, dh),
+            lambda bi, pi, tab, ln, lay: (lay[0], tab[bi, pi], 0, 0, 0))
+        body = _pa_kernel_layered
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,              # page table + length vector
+        num_scalar_prefetch=len(prefetch),  # page table + lengths (+ layer)
         grid=(b, pp),
         in_specs=[
             pl.BlockSpec((1, hkv, dh, cols),
-                         lambda bi, pi, tab, ln: (bi, 0, 0, 0)),
-            # the page-pointer chase: block (pi of slot bi) is physical page
-            # tab[bi, pi] — unmapped entries point at the trash page, whose
-            # positions all sit above lens[bi] and mask to NEG_INF
-            pl.BlockSpec((1, page_size, hkv, dh),
-                         lambda bi, pi, tab, ln: (tab[bi, pi], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, hkv, dh),
-                         lambda bi, pi, tab, ln: (tab[bi, pi], 0, 0, 0)),
+                         lambda bi, pi, *_: (bi, 0, 0, 0)),
+            page_spec,
+            page_spec,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, hkv, dh, cols),
-                               lambda bi, pi, tab, ln: (bi, 0, 0, 0)),
+                               lambda bi, pi, *_: (bi, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((hkv, 1, cols), jnp.float32),    # running max
             pltpu.VMEM((hkv, 1, cols), jnp.float32),    # running denom
@@ -239,14 +268,14 @@ def paged_attention_kernel(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
         ],
     )
     kern = functools.partial(
-        _pa_kernel, page_size=page_size, num_pages=pp, quantized=quantized,
+        body, page_size=page_size, num_pages=pp, quantized=quantized,
         scale=1.0 / math.sqrt(dh), groups=hq // hkv, q_rows=sq)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, dh, cols), q.dtype),
         interpret=interpret,
-    )(table, lens, _to_cols(q, hkv), kdata, vdata,
+    )(*prefetch, _to_cols(q, hkv), kdata, vdata,
       _pow2(kscale), _pow2(vscale))
     out = _from_cols(out, sq)
     return out[:, 0] if squeeze else out
@@ -260,7 +289,8 @@ def paged_attention_jnp(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
                         kscale: jax.Array, vscale: jax.Array,
                         table: jax.Array, lens: jax.Array, *,
                         page_size: int, quantized: bool,
-                        page_chunk: int = 1) -> jax.Array:
+                        page_chunk: int = 1,
+                        layer: jax.Array | None = None) -> jax.Array:
     """Page-walk online-softmax q-block attention as a ``lax.scan`` over the
     page axis, in plain jnp.  Per step it loads ``page_chunk`` int8 pages
     per slot, dequantizes, and folds them into the (m, l, acc) state.  With
@@ -269,11 +299,13 @@ def paged_attention_jnp(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
     scan's dispatch overhead on non-TPU backends while peak residency stays
     bounded by the chunk — the (B, max_len, *feat) fp32 slot view is never
     materialized either way.  KV heads are never expanded: queries and
-    pages take the kernel's layout (``_block_update``)."""
+    pages take the kernel's layout (``_block_update``). With ``layer`` the
+    k/v operands are the stacked (L, P+1, page, Hkv, Dh) pool and each step
+    gathers ``kdata[layer, pages]``."""
     q, squeeze = _norm_q(q)
     b, sq, hq, dh = q.shape
     pp = table.shape[1]
-    hkv = kdata.shape[2]
+    hkv = kdata.shape[-2]
     scale = 1.0 / math.sqrt(dh)
     c = max(1, min(page_chunk, pp))
     nsteps = -(-pp // c)
@@ -285,7 +317,7 @@ def paged_attention_jnp(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
     if nsteps * c != pp:
         # pad the logical page axis with trash-page pointers; their
         # positions sit above every slot's length and mask to NEG_INF
-        trash = kdata.shape[0] - 1
+        trash = kdata.shape[-4] - 1
         table = jnp.pad(table, ((0, 0), (0, nsteps * c - pp)),
                         constant_values=trash)
     qt = _to_cols(q.astype(jnp.float32), hkv)
@@ -300,8 +332,9 @@ def paged_attention_jnp(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
     def body(carry, step):
         m, l, acc = carry
         pages = jax.lax.dynamic_slice_in_dim(table, step * c, c, axis=1)
-        k = kdata[pages].astype(jnp.float32)
-        v = vdata[pages].astype(jnp.float32)
+        idx = pages if layer is None else (layer, pages)
+        k = kdata[idx].astype(jnp.float32)
+        v = vdata[idx].astype(jnp.float32)
         if quantized:
             k = k * ks
             v = v * vs

@@ -419,7 +419,12 @@ class Engine:
         return self.ecfg.fused_attention and sub.mixer_kind == "attn_gqa"
 
     def _sub_decode(self, pp, x, dsub, ssub, table, lens, active, sub,
-                    health=None):
+                    layer, health=None):
+        """One attention sublayer of the batched decode step. ``dsub`` holds
+        the sublayer's stacked (L, P+1, page, *feat) pool leaves and
+        ``layer`` the scan's layer index: the append scatters into the
+        stack and the attention reads it at ``layer``, so no per-layer slab
+        is sliced out or written back. Returns (x, updated leaves)."""
         cfg = self.lm.cfg
         h = rms_norm(x, pp["norm1"]["scale"], cfg.norm_eps)
         positions = A.len_positions(lens, x.shape[0])
@@ -430,7 +435,8 @@ class Engine:
                 health["kv"].append(
                     KC.append_health(new, ssub[name], active, self.pcfg))
         new_dsub = {name: KC.append_token(dsub[name], ssub[name], new, table,
-                                          lens, active, self.pcfg)
+                                          lens, active, self.pcfg,
+                                          layer=layer)
                     for name, new in newd.items()}
         if self._fused_for(sub):
             # fused path: attend straight off the int8 pages — per-page
@@ -440,13 +446,13 @@ class Engine:
             attn = KC.fused_attend(new_dsub["k"], new_dsub["v"], ssub["k"],
                                    ssub["v"], qd["q"][:, 0], table, lens,
                                    self.pcfg, impl=self.ecfg.fused_impl,
-                                   plan=self.plan)
+                                   plan=self.plan, layer=layer)
             attn = attn[:, :d.real_heads].reshape(b, 1,
                                                   d.real_heads * d.head_dim)
             out = apply_site(pp["mixer"]["o"], attn, d.o, cfg)
         else:
             kv = {name: KC.gather_slots(new_dsub[name], ssub[name], table,
-                                        self.pcfg, h.dtype)
+                                        self.pcfg, h.dtype, layer=layer)
                   for name in new_dsub}
             out = _attend(pp["mixer"], qd, kv, sub, cfg, positions)
         x = x + out
@@ -497,13 +503,25 @@ class Engine:
         Returns (logits (B,V), new KV pool, new state pool) — plus, when
         quant-health is on (policy.health), a dict of per-site aggregates
         summed over layers. The health path is Python-gated so a disabled
-        engine's jaxpr is byte-identical to a health-free build."""
+        engine's jaxpr is byte-identical to a health-free build.
+
+        The KV pool's data leaves ride in the layer scan's CARRY, whole and
+        stacked (L, P+1, page, *feat); the scan runs over the layer index
+        with the per-layer params and scale rows. Each layer appends its
+        token with one scatter at [layer, page, off] and the page walk (or
+        the gather fallback) reads the stack at that layer, so the donated
+        pool is updated in place. Scanned as xs/ys instead, XLA slices each
+        layer's slab in, writes it back into a fresh ys buffer and copies
+        that out: several pool-sized copies a step, where the step should
+        move one token per slot per layer. The recurrent-state pool is per
+        slot and small, and stays xs/ys."""
         lm = self.lm
         x = embed_tokens(params, tokens, lm)
 
-        def body(x, scan_in):
-            pp, dl, sl, sd, ss = scan_in
-            new, snew_d, snew_s = {}, {}, {}
+        def body(carry, scan_in):
+            x, data = carry
+            layer, pp, sl, sd, ss = scan_in
+            data, snew_d, snew_s = dict(data), {}, {}
             hc = {"kv": [], "state": []} if self._health else None
             for i, sub in enumerate(lm.period):
                 key = f"sub_{i}"
@@ -511,12 +529,10 @@ class Engine:
                     x, (nd, ns) = self._sub_decode_state(
                         pp[key], x, sd[key], ss[key], active, sub, health=hc)
                     snew_d[key], snew_s[key] = nd, ns
-                    new[key] = dl[key]
                 else:
-                    x, nd = self._sub_decode(pp[key], x, dl[key], sl[key],
-                                             table, lens, active, sub,
-                                             health=hc)
-                    new[key] = nd
+                    x, data[key] = self._sub_decode(
+                        pp[key], x, data[key], sl[key], table, lens, active,
+                        sub, layer, health=hc)
                     snew_d[key], snew_s[key] = sd[key], ss[key]
             if self._health:
                 z32 = jnp.asarray(0, jnp.int32)
@@ -527,16 +543,17 @@ class Engine:
                      sum((s[1] for s in hc["state"]), z32),
                      sum((s[2] for s in hc["state"]), zf),
                      sum((s[3] for s in hc["state"]), zf))
-                return x, (new, snew_d, snew_s, h)
-            return x, (new, snew_d, snew_s)
+                return (x, data), (snew_d, snew_s, h)
+            return (x, data), (snew_d, snew_s)
 
-        x, ys = jax.lax.scan(
-            body, x, (params["layers"], pool["data"], pool["scale_log2"],
-                      spool["data"], spool["scale_log2"]))
+        (x, new_data), ys = jax.lax.scan(
+            body, (x, pool["data"]),
+            (jnp.arange(lm.n_periods, dtype=jnp.int32), params["layers"],
+             pool["scale_log2"], spool["data"], spool["scale_log2"]))
         if self._health:
-            new_data, new_sdata, new_sscale, h = ys
+            new_sdata, new_sscale, h = ys
         else:
-            new_data, new_sdata, new_sscale = ys
+            new_sdata, new_sscale = ys
         x = rms_norm(x, params["final_norm"]["scale"], lm.cfg.norm_eps)
         logits = apply_site(params["head"], x, lm.head, lm.cfg)
         out = (logits[:, 0],

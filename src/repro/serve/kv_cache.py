@@ -194,13 +194,17 @@ def dequantize(q: jax.Array, scale_log2: jax.Array, dtype) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def gather_slots(data_l: jax.Array, scale_l: jax.Array, table: jax.Array,
-                 pcfg: PoolConfig, dtype) -> jax.Array:
+                 pcfg: PoolConfig, dtype, layer: jax.Array | None = None
+                 ) -> jax.Array:
     """Materialize every slot's cache view for one layer.
 
-    data_l: (P+1, page, *feat); scale_l: (num_slots,); table: (B, pages_per_
-    slot). Returns (B, T=max_len, *feat) in ``dtype`` (dequantized on read).
+    data_l: (P+1, page, *feat), or the stacked (L, P+1, page, *feat) leaf
+    with ``layer`` (gathered straight out of the stack, no per-layer slab);
+    scale_l: (num_slots,); table: (B, pages_per_slot). Returns (B,
+    T=max_len, *feat) in ``dtype`` (dequantized on read).
     """
-    g = data_l[table]                                    # (B, pp, page, *f)
+    idx = table if layer is None else (layer, table)
+    g = data_l[idx]                                      # (B, pp, page, *f)
     b = table.shape[0]
     g = g.reshape((b, pcfg.max_len) + g.shape[3:])
     if pcfg.quantized:
@@ -212,12 +216,17 @@ def gather_slots(data_l: jax.Array, scale_l: jax.Array, table: jax.Array,
 def fused_attend(kdata_l: jax.Array, vdata_l: jax.Array, kscale_l: jax.Array,
                  vscale_l: jax.Array, q: jax.Array, table: jax.Array,
                  lens: jax.Array, pcfg: PoolConfig,
-                 impl: str = "auto", plan=None) -> jax.Array:
+                 impl: str = "auto", plan=None,
+                 layer: jax.Array | None = None) -> jax.Array:
     """GQA decode attention straight off the paged pool — the fused
     alternative to ``gather_slots`` + ``models/attention.py::gqa_attend``.
 
     The pool's device layout IS the kernel's: ``kdata_l``/``vdata_l`` are
-    one layer's (P+1, page, Hkv, Dh) page array (row P = trash page),
+    one layer's (P+1, page, Hkv, Dh) page array (row P = trash page), or,
+    with ``layer`` given, the stacked (L, P+1, page, Hkv, Dh) pool leaf the
+    walk reads at that layer. The decode step passes the stacked form: its
+    layer scan carries the whole pool and updates it in place, and a
+    per-layer slab for the kernel operand would copy it every step.
     ``table`` the (B, pages_per_slot) page-pointer rows, ``kscale_l``/
     ``vscale_l`` the (B,) per-slot pow-2 scales, ``lens`` the (B,) incoming
     token positions.  The kernel walks each slot's page list, dequantizes
@@ -236,16 +245,21 @@ def fused_attend(kdata_l: jax.Array, vdata_l: jax.Array, kscale_l: jax.Array,
     from ..kernels.ops import paged_attention
     return paged_attention(q, kdata_l, vdata_l, kscale_l, vscale_l,
                            table, lens, page_size=pcfg.page_size,
-                           quantized=pcfg.quantized, impl=impl, plan=plan)
+                           quantized=pcfg.quantized, impl=impl, plan=plan,
+                           layer=layer)
 
 
 def append_token(data_l: jax.Array, scale_l: jax.Array, new: jax.Array,
                  table: jax.Array, lens: jax.Array, active: jax.Array,
-                 pcfg: PoolConfig) -> jax.Array:
+                 pcfg: PoolConfig, layer: jax.Array | None = None
+                 ) -> jax.Array:
     """Scatter one new token per slot at its own length.
 
     new: (B, 1, *feat) fp; inactive slots are redirected to the trash page.
     Decode appends reuse the slot's prefill scale (clipping into its range).
+    data_l is one layer's (P+1, page, *feat) pages, or the stacked (L, P+1,
+    page, *feat) leaf with ``layer``: one scatter at [layer, page, off],
+    in place when the caller carries the pool.
     """
     b = new.shape[0]
     page_idx = lens // pcfg.page_size
@@ -258,7 +272,8 @@ def append_token(data_l: jax.Array, scale_l: jax.Array, new: jax.Array,
                         pcfg.bits)
     else:
         vals = vals.astype(data_l.dtype)
-    return data_l.at[pages, offs].set(vals)
+    idx = (pages, offs) if layer is None else (layer, pages, offs)
+    return data_l.at[idx].set(vals)
 
 
 def append_tokens(data_l: jax.Array, scale_l: jax.Array, new: jax.Array,

@@ -17,7 +17,10 @@ page-scan the engine uses off-TPU) must agree with it:
 (f) q-block generalization (S query rows at positions lens..lens+S-1 with a
     per-row causal mask — chunked prefill / speculative verify): kernel vs
     oracle over S x heads x storage, BIT-locked to the jnp page-scan, and
-    rank-3 decode == rank-4 S=1.
+    rank-3 decode == rank-4 S=1;
+(g) the stacked pool: a call on the (L, P+1, page, Hkv, Dh) leaf with a
+    ``layer`` index is BIT-identical to the per-layer call on
+    ``data[layer]``, for both fused impls.
 """
 import functools
 
@@ -296,6 +299,45 @@ def test_qblock_ops_wrapper_rank4():
     b = paged_attention(*args, page_size=8, quantized=True, impl="jnp",
                         page_chunk=1)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# (g) stacked pool + layer index == per-layer pool, bitwise
+# ---------------------------------------------------------------------------
+
+LAYERS = 3
+
+
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (6, 2), (3, 1)])  # MHA/GQA/MQA
+@pytest.mark.parametrize("dtype", ["int8", "bfloat16"])
+@pytest.mark.parametrize("s", [1, 4])       # decode / q-block
+def test_stacked_pool_layer_index_equals_per_layer_call(impl, layer, hq, hkv,
+                                                        dtype, s):
+    """The decode step hands the page walk the whole stacked pool leaf and
+    the scan's layer index; the walk must read exactly what the per-layer
+    call reads from ``data[layer]``. Each layer of the stack holds other
+    pages, so a wrong layer cannot pass."""
+    quantized = dtype == "int8"
+    layers = [_synthetic_qblock(20 + i, b=3, pp=4, page=8, hkv=hkv, hq=hq,
+                                dh=16, s=s, quantized=quantized)
+              for i in range(LAYERS)]
+    q, _, _, ks, vs, table, lens = layers[layer]
+    if s == 1:
+        q = q[:, 0]                     # the engine's rank-3 decode query
+    kd = jnp.stack([a[1] for a in layers]).astype(dtype)
+    vd = jnp.stack([a[2] for a in layers]).astype(dtype)
+    if impl == "pallas":
+        fn = functools.partial(PA.paged_attention_kernel, interpret=True)
+    else:
+        fn = functools.partial(PA.paged_attention_jnp, page_chunk=1)
+    kw = dict(page_size=8, quantized=quantized)
+    per_layer = fn(q, kd[layer], vd[layer], ks, vs, table, lens, **kw)
+    stacked = fn(q, kd, vd, ks, vs, table, lens, layer=jnp.int32(layer),
+                 **kw)
+    assert stacked.shape == q.shape
+    np.testing.assert_array_equal(np.asarray(stacked), np.asarray(per_layer))
 
 
 # ---------------------------------------------------------------------------

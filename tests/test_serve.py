@@ -5,7 +5,9 @@
 (b) the int8 KV pool stays within the pow-2 quantization tolerance and cuts
     cache bytes >= 3.5x vs fp32,
 (c) slots are recycled (N > num_slots requests complete), lazily-paged pools
-    preempt and still finish every request.
+    preempt and still finish every request,
+(d) the compiled decode step updates the KV pool in place: no pool-sized
+    copy, broadcast, slice or update, and temporaries under one pool leaf.
 """
 import jax
 import jax.numpy as jnp
@@ -232,6 +234,29 @@ def test_preemption_under_page_pressure():
     res = eng.run()
     assert all(len(res[r].tokens) == 14 for r in rids)
     assert eng.summary()["preemptions"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# (d) the decode step's pool traffic
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_decode_step_updates_pool_in_place(fused):
+    """The pool rides in the decode scan's carry: the compiled step holds no
+    pool-shaped copy, broadcast, dynamic-slice or dynamic-update-slice, and
+    its temporaries stay under one pool leaf. The pool is made large
+    (4,096 pages) so one leaf outweighs every other temporary; a step that
+    scans the pool as xs/ys holds about three leaves of temporaries."""
+    from hlo_pool import decode_pool_report
+    cfg, lm, params = _setup()
+    assert all(sub.mixer_kind == "attn_gqa" for sub in lm.period)
+    pcfg = PoolConfig(num_slots=4, page_size=4, pages_per_slot=16,
+                      num_pages=4096, quantized=True)
+    eng = Engine(lm, params, EngineConfig(pool=pcfg, fused_attention=fused),
+                 PLAN)
+    bad, temp, leaf = decode_pool_report(eng)
+    assert not bad, "\n".join(bad)
+    assert temp < leaf, (temp, leaf)
 
 
 # ---------------------------------------------------------------------------

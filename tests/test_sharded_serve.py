@@ -8,7 +8,10 @@ mesh-less reference:
 (a) engine decode, gather + fused paged-attention, on a TP mesh that shards
     the paged KV pool over KV heads — attention arch (1x8) and the jamba
     hybrid (4x2, state pool sharded over d_inner, MoE over the model axis),
-(b) prefix-cache admission + COW forks on head-sharded pages,
+(b) prefix-cache admission + COW forks on head-sharded pages, and the
+    TP engine's compiled decode step updating the head-sharded pool in
+    place (no pool-sized copy, slice or update; temporaries under one
+    pool leaf's shard),
 (c) the dp-only shard_map train step: step-1 loss bitwise vs the mesh-less
     step, and a jaxpr walk proving the int8 gradient wire is the ONLY
     payload-sized collective in the step.
@@ -76,6 +79,24 @@ if CASE in ("engine_attn", "engine_jamba"):
                             fused_attention=fused)
         assert got == ref, (fused, got, ref)
         print("OK", CASE, "fused" if fused else "gather", "token-identical")
+
+elif CASE == "decode_in_place":
+    import sys
+    sys.path.insert(0, "tests")
+    from hlo_pool import decode_pool_report
+    cfg, lm, params = setup("internlm2-1.8b", d_model=256, num_heads=16,
+                            num_kv_heads=8, d_ff=160)
+    plan = make_plan(make_mesh((1, 8), ("data", "model")), "tp")
+    pcfg = PoolConfig(num_slots=4, page_size=4, pages_per_slot=16,
+                      num_pages=4096, quantized=True)
+    for fused in (False, True):
+        eng = Engine(lm, params, EngineConfig(pool=pcfg,
+                                              fused_attention=fused), plan)
+        bad, temp, leaf = decode_pool_report(eng)
+        assert not bad, bad
+        assert temp < leaf, (fused, temp, leaf)
+        print("OK", CASE, "fused" if fused else "gather", "temp", temp,
+              "leaf shard", leaf)
 
 elif CASE == "prefix":
     cfg, lm, params = setup("internlm2-1.8b", d_model=256, num_heads=16,
@@ -179,7 +200,8 @@ elif CASE == "dp_train":
           len(colls) - len(big), "small")
 """
 
-CASES = ["engine_attn", "engine_jamba", "prefix", "dp_train"]
+CASES = ["engine_attn", "engine_jamba", "prefix", "dp_train",
+         "decode_in_place"]
 
 
 @pytest.mark.parametrize("case", CASES)

@@ -66,6 +66,21 @@ def test_paged_attention_kernel(one_chip, q_rows, dtype):
              ((B,), jnp.int32))
 
 
+@pytest.mark.parametrize("dtype", [jnp.int8, jnp.bfloat16])
+@pytest.mark.parametrize("q_rows", [1, 5])
+def test_paged_attention_kernel_stacked_pool(one_chip, q_rows, dtype):
+    """The decode step's form: the stacked (L, P+1, page, Hkv, Dh) pool
+    leaf and a layer index, read through the page BlockSpec's index map."""
+    kern = functools.partial(PA.paged_attention_kernel, page_size=PAGE,
+                             quantized=dtype == jnp.int8, interpret=False)
+    page = ((LAYERS, POOL_PAGES, PAGE, HKV, DH), dtype)
+    _compile(lambda q, k, v, ks, vs, t, n, layer: kern(
+                 q, k, v, ks, vs, t, n, layer=layer),
+             one_chip, ((B, q_rows, HQ, DH), jnp.bfloat16), page, page,
+             ((B,), jnp.float32), ((B,), jnp.float32), ((B, PP), jnp.int32),
+             ((B,), jnp.int32), ((), jnp.int32))
+
+
 SPEC = QuantSpec("pow2", 8, 0, "int8", "per_tensor_max")
 
 
