@@ -5,11 +5,21 @@ Everything a cell needs is found by the names that ``BENCHMARK.json``
 gives: a workload names a configuration (``configs/<name>.json``) and a
 traffic mix (``traffic/<name>.json``); the traffic file's ``kind`` names
 the load loop (``loops/<kind>.py``); a per-layer metric's name is its
-reader (``layers/<name>.py``). Adding a cell, a configuration or a metric
-adds files and entries and edits none of these.
+reader (``layers/<name>.py``); a configuration's ``program.layout`` names
+its architecture's layout (``layouts/<kind>.py``: the program's model
+config, the per-layer tensors, their place in the program's tree, and the
+work counts that ``work.py`` forwards to). Adding a cell, a configuration
+or a metric adds files and entries and edits none of these.
+
+A configuration of a new architecture adds ``configs/<name>.json``, its
+layout ``layouts/<kind>.py``, its plain reference ``reference/<name>.py``,
+the limits of each of its cells ``limits/<cell>.json``, readers
+``layers/<metric>.py`` for any metric its cells add, and its entries in
+``BENCHMARK.json``.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import math
@@ -58,6 +68,21 @@ def load_module(path: Path, name: str):
 
 def loop_for(kind: str):
     return load_module(BENCH / "loops" / f"{kind}.py", f"bench_loop_{kind}")
+
+
+@functools.cache
+def layout_for(kind: str):
+    """The layout module ``layouts/<kind>.py``, loaded once a process:
+    ``work.py`` asks for it at every count."""
+    path = BENCH / "layouts" / f"{kind}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no layout {kind!r}: {path} is missing")
+    return load_module(path, f"bench_layout_{kind}")
+
+
+def layout_of(c: dict):
+    """The layout a configuration file names (``program.layout``)."""
+    return layout_for(c["program"]["layout"])
 
 
 def layer_reader(metric: str):

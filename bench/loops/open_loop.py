@@ -104,11 +104,11 @@ class _Stamps:
 
 def setup(ctx):
     """The model, its seeded weights and a warm engine."""
-    from program import build, dense_params
+    import program
     from repro.obs import TraceRecorder
     c, t = ctx.config, ctx.traffic
-    lm = build(c)
-    params = dense_params(c, lm, ctx.seed)
+    lm = program.build(c)
+    params = program.params(c, lm, ctx.seed)
     recorder = TraceRecorder(capacity=1 << 20)
     eng = _engine(c, t, lm, params, recorder)
     warmed = _warm(eng, c, t, ctx.seed)

@@ -6,9 +6,10 @@ key/value head h // (heads / kv_heads) -> output projection -> residual;
 RMSNorm -> SwiGLU feed-forward -> residual; final RMSNorm; untied head.
 
 No kernels, no cache, no batching: one sequence, every matmul in float32
-at ``Precision.HIGHEST``. Weights come from ``weights.py`` by seed, one
-layer at a time inside the layer loop, so the reference holds one layer's
-float32 weights at once. It imports nothing of the program.
+at ``Precision.HIGHEST``. Weights come by seed from ``weights.py`` and
+from the ``dense_gqa`` layout's ``layer_weights``, one layer at a time
+inside the layer loop, so the reference holds one layer's float32 weights
+at once. It imports nothing of the program.
 
 ``lowp=True`` is the control for the configuration's bfloat16 compute:
 the same forward with every projection an int8 x int8 product (per-row
@@ -24,6 +25,9 @@ import jax
 import jax.numpy as jnp
 
 import weights as W
+from common import layout_for
+
+LAYOUT = layout_for("dense_gqa")
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -91,7 +95,7 @@ def _logits(seed_arr, tokens, rows, c_items, lowp):
 
     def body(x, i):
         w = jax.tree.map(lambda a: a.astype(jnp.float32),
-                         W.dense_layer(seed_arr, c, i, dtype))
+                         LAYOUT.layer_weights(seed_arr, c, i, dtype))
         return layer(x, w, c, lowp), None
 
     x, _ = jax.lax.scan(body, x, jnp.arange(c["num_hidden_layers"]))
@@ -124,12 +128,12 @@ def logits_at(seed: int, c: dict, tokens, rows, lowp: bool = False,
                    lowp)[:len(rows)]
 
 
-def served_gap(seed: int, c: dict, prompt, served, lowp: bool = False,
-               length: int = 0) -> float:
-    """The widest gap by which a served token's reference logit lies below
-    the reference's best at its position. With ``lowp``, the control's
-    reading instead: at each of those positions, the gap of the token the
-    low-precision forward puts first."""
+def served_gaps(seed: int, c: dict, prompt, served, lowp: bool = False,
+                length: int = 0):
+    """At each position of the served tokens, the gap by which the served
+    token's reference logit lies below the reference's best there (0 where
+    it is the best). With ``lowp``, the control's reading instead: the gap
+    of the token the low-precision forward puts first."""
     import numpy as np
     seq = list(prompt) + list(served[:-1])
     rows = np.arange(len(prompt) - 1, len(seq))
@@ -138,4 +142,4 @@ def served_gap(seed: int, c: dict, prompt, served, lowp: bool = False,
                                  length=length), axis=-1)
             if lowp else jnp.asarray(served))
     got = jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
-    return float(jnp.max(jnp.max(ref, axis=-1) - got))
+    return np.asarray(jnp.max(ref, axis=-1) - got)

@@ -87,17 +87,28 @@ class Context:
                 "bench_reference")
         return self._reference
 
-    def check_served(self, picked, lowp: bool = False) -> list[dict]:
-        """The widest gap of the served tokens below the reference's best,
-        over the ``(prompt, served)`` pairs picked; with ``lowp`` the
-        control's reading at the same positions (``tools/readings.py``)."""
+    def served_gaps(self, picked, lowp: bool = False):
+        """Over the ``(prompt, served)`` pairs picked, position by position,
+        the gap of each served token below the reference's best; with
+        ``lowp`` the control's reading at the same positions."""
+        import numpy as np
         ref = self.reference()
         t = self.traffic
         length = t["prompt_len"]["max"] + t["output_len"]["max"]
-        gap = max(ref.served_gap(self.seed, self.config, prompt, served,
-                                 lowp=lowp, length=length)
-                  for prompt, served in picked)
-        return [self.check("served_logit_gap", gap)]
+        return np.concatenate([
+            ref.served_gaps(self.seed, self.config, prompt, served,
+                            lowp=lowp, length=length)
+            for prompt, served in picked])
+
+    def check_served(self, picked, lowp: bool = False) -> list[dict]:
+        """Over the pairs picked, the widest gap of the served tokens below
+        the reference's best, and the mean gap over every position, which
+        grows as the square of the logits' error where the widest grows as
+        the error itself (``tools/readings.py`` reads the controls with
+        ``lowp``)."""
+        gaps = self.served_gaps(picked, lowp)
+        return [self.check("served_logit_gap", float(gaps.max())),
+                self.check("served_logit_gap_mean", float(gaps.mean()))]
 
 
 def _fail(msg: str) -> int:

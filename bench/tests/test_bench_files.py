@@ -1,6 +1,7 @@
-"""Every entry of BENCHMARK.json resolves to its files by name, each
-traffic mix generates from a seed, and the command refuses to run where
-there is no TPU."""
+"""Every entry of BENCHMARK.json resolves to its files by name (a
+configuration to its layout too), each traffic mix generates from a seed,
+and the command refuses to run where there is no TPU."""
+import ast
 import json
 import os
 import re
@@ -127,3 +128,29 @@ def test_refuses_without_the_program(tmp_path):
                     ignore=shutil.ignore_patterns("__pycache__"))
     p = _run(tmp_path)
     assert p.returncode != 0 and "{" not in p.stdout
+
+
+LAYOUT_API = ("model_config", "layer_weights", "params", "layer_params",
+              "head_params", "attention_flops", "decode_token_flops",
+              "paged_attention_bytes")
+
+
+@pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_layout_resolves(cfg):
+    c = common.load_config(cfg["name"])
+    path = common.BENCH / "layouts" / f"{c['program']['layout']}.py"
+    lay = common.layout_of(c)
+    assert all(callable(getattr(lay, f, None)) for f in LAYOUT_API)
+    # the program is imported inside functions only, so a reference may
+    # take its tensors from here
+    top = ast.parse(path.read_text()).body
+    names = [a.name for n in top if isinstance(n, ast.Import)
+             for a in n.names]
+    names += [n.module for n in top if isinstance(n, ast.ImportFrom)]
+    assert not [m for m in names if m.split(".")[0] in ("repro", "program")]
+
+
+def test_unknown_layout_names_the_missing_file():
+    with pytest.raises(FileNotFoundError,
+                       match=r"layouts/no_such_kind\.py is missing"):
+        common.layout_for("no_such_kind")

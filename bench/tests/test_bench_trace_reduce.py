@@ -7,6 +7,8 @@ from pathlib import Path
 
 import pytest
 
+import common
+import peaks
 import trace_reduce as TR
 
 FIXTURE = Path(__file__).parent / "fixtures" / "decode_step.xplane.pb"
@@ -61,3 +63,22 @@ def test_by_hand():
     assert TR.op_seconds(red) == pytest.approx({"a": 15e-9, "b": 10e-9,
                                                 "c": 5e-9})
     assert len(TR.module_runs(red, "step")) == 1
+
+
+# what the readers gave on this trace before work.py forwarded to layouts
+PINNED = {"decode.mfu": 16.85237935498895,
+          "paged_attention_roofline": 1.8038793151991206}
+
+
+@pytest.mark.parametrize("metric", sorted(PINNED))
+def test_readers_on_recorded_trace(red, metric):
+    # the fixture's page walk: 4 query heads, 2 KV heads of 128, one layer,
+    # int8 pages; two rows of 20 and 50 keys a step
+    c = dict(common.load_config("internlm2-1.8b"), num_hidden_layers=1,
+             hidden_size=512, num_attention_heads=4, num_key_value_heads=2)
+    rec = {"steps": [{"decode_keys": [20, 50]}] * 3, "trace_steps": [0, 2],
+           "pool_itemsize": 1, "act_itemsize": 4}
+    view = {"records": rec, "trace": red, "config": c, "traffic": {},
+            "peaks": peaks.peaks_for("TPU v5 lite")}
+    assert common.layer_reader(metric).read(view) == pytest.approx(
+        PINNED[metric], rel=1e-12)

@@ -1,13 +1,15 @@
 """The yardstick's arithmetic against hand counts at the program's
 ``get_reduced`` widths of internlm2-1.8b (2 layers, d=64, 4 heads, 2 KV
-heads, head 16, ffn 160, vocab 64), and the peak table."""
+heads, head 16, ffn 160, vocab 64), reached through ``work.py``'s dispatch
+to the configuration's layout, and the peak table."""
 import pytest
 
 import peaks
 import work
 from tiny import WIDTHS
 
-C = {**WIDTHS, "torch_dtype": "float32"}
+C = {**WIDTHS, "torch_dtype": "float32",
+     "program": {"layout": "dense_gqa"}}
 
 
 def test_dense_counts():

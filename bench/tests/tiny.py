@@ -32,7 +32,9 @@ def context(config, traffic, seed=7, seconds=1.0, limits=None):
     import jax
     import run
     args = SimpleNamespace(seed=seed, seconds=seconds, trace=0)
+    limits = limits or {"served_logit_gap": 1e9,
+                        "served_logit_gap_mean": 1e9}
     ctx = run.Context(args, {"name": "tiny", "chips": 1}, config, traffic,
-                      jax.devices()[:1], limits or {"served_logit_gap": 1e9})
+                      jax.devices()[:1], limits)
     ctx.t_process = time.monotonic()
     return ctx

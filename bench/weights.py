@@ -1,8 +1,9 @@
 """Random weights from a seed, by tensor name and layer.
 
-Both the system under test and the plain references take their weights
-from here, so the two see the same numbers without either reading what the
-other made. Each tensor is drawn from its own key, folded from the seed's
+A configuration's layout (``layouts/<kind>.py``) draws its per-layer
+tensors with these helpers, and both the system under test and the plain
+references take their weights from there and from here, so the two see the
+same numbers without either reading what the other made. Each tensor is drawn from its own key, folded from the seed's
 two words, the tensor's name and its layer index. The seed enters as a
 traced array, so one compiled program makes the weights of every seed.
 
@@ -45,26 +46,6 @@ def matrix(seed_arr, name: str, shape, dtype, layer=0, std=None):
 def norm_scale(seed_arr, name: str, dim: int, layer=0):
     return 1.0 + 0.1 * jax.random.normal(tensor_key(seed_arr, name, layer),
                                          (dim,), jnp.float32)
-
-
-def dense_shapes(c: dict) -> dict:
-    """Per-layer matrix shapes of a dense GQA decoder, (fan_in, fan_out)."""
-    d, f = c["hidden_size"], c["intermediate_size"]
-    dh = d // c["num_attention_heads"]
-    hq, hkv = c["num_attention_heads"], c["num_key_value_heads"]
-    return {"wq": (d, hq * dh), "wk": (d, hkv * dh), "wv": (d, hkv * dh),
-            "wo": (hq * dh, d), "w_gate": (d, f), "w_up": (d, f),
-            "w_down": (f, d)}
-
-
-def dense_layer(seed_arr, c: dict, layer, dtype) -> dict:
-    """One decoder layer's weights (``layer`` may be traced)."""
-    d = c["hidden_size"]
-    out = {name: matrix(seed_arr, name, shape, dtype, layer)
-           for name, shape in dense_shapes(c).items()}
-    out["attn_norm"] = norm_scale(seed_arr, "attn_norm", d, layer)
-    out["ffn_norm"] = norm_scale(seed_arr, "ffn_norm", d, layer)
-    return out
 
 
 def embedding(seed_arr, c: dict, dtype):
