@@ -17,6 +17,7 @@ ARCHS = {
     "llava-next-34b": "llava_next_34b",
     "rwkv6-1.6b": "rwkv6_1_6b",
     "moonshot-v1-16b": "moonshot_v1_16b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "deepseek-v2-236b": "deepseek_v2_236b",
 }
 

@@ -69,13 +69,25 @@ class MoEConfig:
     num_shared: int = 0             # shared (always-on) experts, DeepSeek-style
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01   # load-balance aux loss
+    scoring: str = "softmax"        # "softmax" | "sigmoid"; sigmoid is
+                                    # DeepSeek-V3's noaux_tc: a per-expert
+                                    # correction bias picks the experts and
+                                    # does not enter their weights
+    norm_topk_prob: bool = True     # normalise the k picked weights
+    routed_scaling_factor: float = 1.0
+    n_group: int = 1                # group-limited routing: only 1
+    dropless: bool = False          # every routed token reaches its expert
+                                    # at any load (no capacity)
+    experts_held: int = 0           # experts this chip holds (0: all); the
+                                    # router still spans num_experts
+    expert_offset: int = 0          # global index of the first held expert
 
 
 @dataclass(frozen=True)
 class MLAConfig:
     """DeepSeek-V2 multi-head latent attention."""
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536  # None: q projected straight from x
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -119,6 +131,10 @@ class ModelConfig:
     period: int = 1                 # 1 => homogeneous stack
     attn_positions: tuple[int, ...] = ()   # positions within period that are attention
     moe_positions: tuple[int, ...] = ()    # positions within period whose FFN is MoE
+    # leading layers with a dense FFN of their own width, run before the
+    # periods (DeepSeek's first_k_dense_replace); num_layers counts them
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0             # their FFN width (0 => d_ff)
     # modality frontend stub: "none" | "audio" | "vision"
     frontend: str = "none"
     is_encoder: bool = False        # encoder-only (no causal mask, no decode)

@@ -1,7 +1,9 @@
-"""moonshot-v1-16b-a3b [moe]: 48L d_model=2048 16H (GQA kv=16) expert
-d_ff=1408 vocab=163840, MoE 64e top-6 [hf:moonshotai/Moonlight-16B-A3B].
-Per the assigned one-line spec: all layers MoE, no shared experts (HF config
-has 2 shared + first dense layer — deviation recorded in DESIGN.md §4).
+"""moonshot-v1-16b [moe]: a GQA, all-MoE stand-in of the zoo, not
+Moonlight: 48L d_model=2048 16H (GQA kv=16) expert d_ff=1408 vocab=163840,
+MoE 64e top-6 with softmax routing and capacity drops, no shared experts
+and no dense layer. Moonlight-16B-A3B itself (27 layers, MLA, 2 shared
+experts, a dense layer 0, sigmoid routing) is ``moonlight_16b_a3b.py``.
+Its numbers stay as they are: tests/test_prefix_cache.py serves them.
 The 163,840-row embedding is the zoo's biggest TTM win when --tt is on."""
 from .base import MoEConfig, ModelConfig
 

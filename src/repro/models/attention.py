@@ -294,8 +294,9 @@ def gqa_init_cache(d: GQADef, batch: int, max_len: int, dtype) -> dict:
 
 @dataclass(frozen=True)
 class MLADef:
-    q_down: SiteDef
-    q_up: SiteDef
+    q_down: SiteDef | None  # x -> q_lora (None without a q low-rank)
+    q_up: SiteDef | None    # q_lora -> H * (nope + rope)
+    q: SiteDef | None       # x -> H * (nope + rope), when q_lora_rank is None
     kv_down: SiteDef        # -> kv_lora + rope dim
     k_up: SiteDef           # kv_lora -> H * qk_nope
     v_up: SiteDef           # kv_lora -> H * v_head
@@ -305,13 +306,17 @@ class MLADef:
 
 
 def make_mla(cfg: ModelConfig) -> MLADef:
+    """DeepSeek-V2 MLA; with ``q_lora_rank`` None (Moonlight, DeepSeek-V2-
+    Lite) q is one projection of x, with no q norm."""
     m = cfg.mla
     h = cfg.num_heads
+    qdim = h * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+    low = m.q_lora_rank is not None
     return MLADef(
-        q_down=make_site(cfg, "attn_qkv", m.q_lora_rank, cfg.d_model),
-        q_up=make_site(cfg, "attn_qkv",
-                       h * (m.qk_nope_head_dim + m.qk_rope_head_dim),
-                       m.q_lora_rank),
+        q_down=make_site(cfg, "attn_qkv", m.q_lora_rank, cfg.d_model)
+        if low else None,
+        q_up=make_site(cfg, "attn_qkv", qdim, m.q_lora_rank) if low else None,
+        q=None if low else make_site(cfg, "attn_qkv", qdim, cfg.d_model),
         kv_down=make_site(cfg, "attn_qkv", m.kv_lora_rank + m.qk_rope_head_dim,
                           cfg.d_model),
         k_up=make_site(cfg, "attn_qkv", h * m.qk_nope_head_dim, m.kv_lora_rank),
@@ -322,10 +327,15 @@ def make_mla(cfg: ModelConfig) -> MLADef:
 
 def init_mla(key: jax.Array, d: MLADef, cfg: ModelConfig) -> dict:
     ks = jax.random.split(key, 8)
+    p = {}
+    if d.q is None:
+        p["q_down"] = init_site(ks[0], d.q_down, cfg)
+        p["q_norm"] = {"scale": jnp.ones((d.m.q_lora_rank,), jnp.float32)}
+        p["q_up"] = init_site(ks[1], d.q_up, cfg)
+    else:
+        p["q"] = init_site(ks[0], d.q, cfg)
     return {
-        "q_down": init_site(ks[0], d.q_down, cfg),
-        "q_norm": {"scale": jnp.ones((d.m.q_lora_rank,), jnp.float32)},
-        "q_up": init_site(ks[1], d.q_up, cfg),
+        **p,
         "kv_down": init_site(ks[2], d.kv_down, cfg),
         "kv_norm": {"scale": jnp.ones((d.m.kv_lora_rank,), jnp.float32)},
         "k_up": init_site(ks[3], d.k_up, cfg),
@@ -337,10 +347,13 @@ def init_mla(key: jax.Array, d: MLADef, cfg: ModelConfig) -> dict:
 def _mla_q(params, x, d: MLADef, cfg, positions):
     b, s, _ = x.shape
     m = d.m
-    cq = apply_site(params["q_down"], x, d.q_down, cfg)
-    cq = C.rms_norm(cq, params["q_norm"]["scale"], cfg.norm_eps)
-    q = apply_site(params["q_up"], cq, d.q_up, cfg).reshape(
-        b, s, d.num_heads, m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if d.q is None:
+        cq = apply_site(params["q_down"], x, d.q_down, cfg)
+        cq = C.rms_norm(cq, params["q_norm"]["scale"], cfg.norm_eps)
+        q = apply_site(params["q_up"], cq, d.q_up, cfg)
+    else:
+        q = apply_site(params["q"], x, d.q, cfg)
+    q = q.reshape(b, s, d.num_heads, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
     q_rope = rope(q_rope, positions, cfg.rope_theta)
     return q_nope, q_rope

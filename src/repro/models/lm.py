@@ -1,16 +1,21 @@
 """Unified LM: every arch in the zoo is an instance of this module.
 
-Structure: embed → scan over *periods* of sublayers → final norm → head.
-A period is a fixed pattern of sublayers (1 for homogeneous stacks; 8 for
-Jamba's 7-Mamba+1-attention interleave). Layer params are stacked on a
-leading axis and consumed by ``lax.scan`` (compile time independent of
-depth), with configurable remat.
+Structure: embed → leading layers → scan over *periods* of sublayers →
+final norm → head. A period is a fixed pattern of sublayers (1 for
+homogeneous stacks; 8 for Jamba's 7-Mamba+1-attention interleave). Layer
+params are stacked on a leading axis and consumed by ``lax.scan`` (compile
+time independent of depth), with configurable remat. Leading layers
+(DeepSeek's ``first_k_dense_replace``: the same mixer with a dense FFN of
+its own width) run unrolled before the scan, with params of their own
+(``params["lead"]``); their caches take the first entries of the period's
+stacked caches, so every cache and pool is one stack of ``n_stack`` layers.
 
 Every weight matrix is a *site* (dense or TT-factorized per config); TT
 sites contribute the rank-shrinkage prior and receive closed-form λ updates.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from dataclasses import dataclass
 from functools import partial
@@ -44,6 +49,14 @@ class LMDef:
     head: SiteDef
     period: tuple[SubDef, ...]
     n_periods: int
+    lead: tuple[SubDef, ...] = ()   # leading layers, before the periods
+                                    # (single-sublayer periods only)
+
+    @property
+    def n_stack(self) -> int:
+        """Depth of the stacked caches and pools: the leading layers, then
+        one entry per period."""
+        return len(self.lead) + self.n_periods
 
 
 # ---------------------------------------------------------------------------
@@ -69,6 +82,12 @@ def build_lm(cfg: ModelConfig) -> LMDef:
             return "moe", M.make_moe(cfg)
         return "ffn", F.make_ffn(cfg)
 
+    lead: tuple[SubDef, ...] = ()
+    n_lead = cfg.first_dense_layers
+    if n_lead and cfg.family not in ("dense", "moe"):
+        raise NotImplementedError(
+            f"leading dense layers on a {cfg.family!r} stack")
+
     if cfg.family == "ssm_rwkv6":
         mk, mx = mixer_for("rwkv6")
         subs.append(SubDef(mk, mx, None, None))
@@ -85,13 +104,29 @@ def build_lm(cfg: ModelConfig) -> LMDef:
         mk, mx = mixer_for("attn")
         fk, fd = ffn_for(cfg.moe.num_experts > 0)
         subs.append(SubDef(mk, mx, fk, fd))
-        n_periods = cfg.num_layers
+        n_periods = cfg.num_layers - n_lead
+        lead = (SubDef(mk, mx, "ffn",
+                       F.make_ffn(cfg, cfg.dense_d_ff or None)),) * n_lead
 
     embed = None
     if cfg.frontend != "audio":
         embed = make_site(cfg, "embed", cfg.vocab_size, cfg.d_model)
     head = make_site(cfg, "head", cfg.vocab_size, cfg.d_model)
-    return LMDef(cfg, embed, head, tuple(subs), n_periods)
+    return LMDef(cfg, embed, head, tuple(subs), n_periods, lead)
+
+
+def dropless(lm: LMDef) -> LMDef:
+    """``lm`` with every MoE sublayer routing dropless, as a server routes:
+    with a capacity, a served token's output would depend on the other
+    tokens of its prefill chunk or decode batch."""
+    def one(sub: SubDef) -> SubDef:
+        if sub.ffn_kind != "moe" or sub.ffn.dropless:
+            return sub
+        return dataclasses.replace(
+            sub, ffn=dataclasses.replace(sub.ffn, dropless=True))
+
+    return dataclasses.replace(lm, period=tuple(map(one, lm.period)),
+                               lead=tuple(map(one, lm.lead)))
 
 
 def _init_sub(key: jax.Array, sub: SubDef, cfg: ModelConfig) -> dict:
@@ -137,6 +172,10 @@ def init_lm(key: jax.Array, lm: LMDef) -> dict:
 
     params["layers"] = jax.vmap(init_period)(
         jax.random.split(kl, lm.n_periods))
+    if lm.lead:
+        kd = jax.random.split(jax.random.fold_in(kl, 1), len(lm.lead))
+        params["lead"] = [_init_sub(kd[j], sub, cfg)
+                          for j, sub in enumerate(lm.lead)]
     params["final_norm"] = {"scale": jnp.ones((cfg.d_model,), jnp.float32)}
     params["head"] = init_site(kh, lm.head, cfg)
     return params
@@ -196,8 +235,7 @@ def tt_embed_lookup(eparams: dict, tokens: jax.Array, site: SiteDef,
 
 def _sub_forward(pp: dict, x: jax.Array, sub: SubDef, cfg: ModelConfig,
                  plan: ShardPlan, positions: jax.Array, *,
-                 return_cache: bool, token_mask: jax.Array | None = None,
-                 capacity_tokens: int | None = None):
+                 return_cache: bool, token_mask: jax.Array | None = None):
     """One sublayer (mixer + optional ffn). Returns (x, aux, cache_entry).
 
     ``token_mask``: optional (B, S) bool of real tokens — serve-prefill
@@ -246,8 +284,7 @@ def _sub_forward(pp: dict, x: jax.Array, sub: SubDef, cfg: ModelConfig,
         if sub.ffn_kind == "moe":
             out, a = M.moe_forward(pp["moe"], h, sub.ffn, cfg,
                                    mesh=plan.mesh, dp_axes=plan.dp_axes,
-                                   token_mask=token_mask,
-                                   capacity_tokens=capacity_tokens)
+                                   token_mask=token_mask)
             aux = aux + a
         else:
             out = F.ffn_forward(pp["ffn"], h, sub.ffn, cfg)
@@ -282,8 +319,7 @@ def lm_forward(params: dict, lm: LMDef, plan: ShardPlan, *,
                embeds: jax.Array | None = None,
                return_cache: bool = False,
                token_mask: jax.Array | None = None,
-               scales: dict | None = None,
-               capacity_tokens: int | None = None):
+               scales: dict | None = None):
     """Train/prefill forward.
 
     tokens: (B, S) int32 and/or embeds: (B, P, D) frontend outputs (vlm:
@@ -297,7 +333,8 @@ def lm_forward(params: dict, lm: LMDef, plan: ShardPlan, *,
     managed scales, and the return gains a 4th element ``obs`` — the
     per-layer mean |activation| statistic the scale manager consumes
     (``policy.update_scales(scales, obs)`` in the train step).
-    Returns (logits, aux, cache|None) or (logits, aux, cache|None, obs).
+    Returns (logits, aux, cache|None) or (logits, aux, cache|None, obs);
+    cache leaves are stacked (n_stack, B, S, ...), leading layers first.
     """
     cfg = lm.cfg
     # the edge quantizes fwd AND bwd, so both managed sites must be present
@@ -317,27 +354,44 @@ def lm_forward(params: dict, lm: LMDef, plan: ShardPlan, *,
         x = _act_quant_edge(x, scales, cfg)
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
 
+    def sub_step(x, pp, sub):
+        x, a, c = _sub_forward(pp, x, sub, cfg, plan, positions,
+                               return_cache=return_cache,
+                               token_mask=token_mask)
+        if quant_acts:
+            x = _act_quant_edge(x, scales, cfg)
+        return x, a, c
+
+    def amean_of(x):
+        return jnp.mean(jnp.abs(jax.lax.stop_gradient(x).astype(jnp.float32)))
+
+    aux, amean = jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)
+    lead_caches = []
+    for pp, sub in zip(params.get("lead", ()), lm.lead):
+        x, a, c = _remat_wrap(partial(sub_step, sub=sub), cfg)(x, pp)
+        aux = aux + a
+        if quant_acts:
+            amean = amean + amean_of(x)
+        lead_caches.append(c)
+
     def body(carry, pp):
         x, aux, amean = carry
         caches = {}
         for i, sub in enumerate(lm.period):
-            x, a, c = _sub_forward(pp[f"sub_{i}"], x, sub, cfg, plan,
-                                   positions, return_cache=return_cache,
-                                   token_mask=token_mask,
-                                   capacity_tokens=capacity_tokens)
-            if quant_acts:
-                x = _act_quant_edge(x, scales, cfg)
+            x, a, c = sub_step(x, pp[f"sub_{i}"], sub)
             aux = aux + a
             caches[f"sub_{i}"] = c
         if quant_acts:
-            amean = amean + jnp.mean(jnp.abs(
-                jax.lax.stop_gradient(x).astype(jnp.float32)))
+            amean = amean + amean_of(x)
         return (x, aux, amean), caches
 
     body = _remat_wrap(body, cfg)
-    (x, aux, amean), caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        params["layers"])
+    (x, aux, amean), caches = jax.lax.scan(body, (x, aux, amean),
+                                           params["layers"])
+    if lead_caches:
+        caches = {"sub_0": jax.tree.map(
+            lambda c, *ls: jnp.concatenate([jnp.stack(ls), c]),
+            caches["sub_0"], *lead_caches)}
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     logits = apply_site(params["head"], x, lm.head, cfg)
     if cfg.logits_softcap > 0:
@@ -346,32 +400,35 @@ def lm_forward(params: dict, lm: LMDef, plan: ShardPlan, *,
     cache = caches if return_cache else None
     if scales is None:
         return logits, aux, cache
-    obs = {"activation": (amean / lm.n_periods)[None]} if quant_acts else {}
+    obs = {"activation": (amean / lm.n_stack)[None]} if quant_acts else {}
     return logits, aux, cache, obs
 
 
 def sub_ffn_decode(pp: dict, x: jax.Array, sub: SubDef, cfg: ModelConfig,
                    plan: ShardPlan,
                    token_mask: jax.Array | None = None,
-                   capacity_tokens: int | None = None) -> jax.Array:
+                   with_hits: bool = False):
     """Post-mixer FFN/MoE half of a sublayer (shared by the static decode
     path and repro.serve's paged decode/chunk steps).
 
     ``token_mask``: optional (B, S) bool of real tokens — inactive serve
     slots / prefill-chunk padding are masked out of the MoE router so junk
     tokens never consume expert capacity (dense FFN ignores it: per-token
-    math can't interfere across rows)."""
+    math can't interfere across rows). ``with_hits``: also return the MoE
+    layer's int32 [held experts hit, tokens routed to them] (zeros for a
+    dense FFN; see ``moe_forward``)."""
+    none = [jnp.zeros((2,), jnp.int32)] if with_hits else []
     if sub.ffn_kind is None:
-        return x
+        return (x, *none) if with_hits else x
     h = rms_norm(x, pp["norm2"]["scale"], cfg.norm_eps)
     if sub.ffn_kind == "moe":
-        out, _ = M.moe_forward(pp["moe"], h, sub.ffn, cfg,
-                               mesh=plan.mesh, dp_axes=plan.dp_axes,
-                               token_mask=token_mask,
-                               capacity_tokens=capacity_tokens)
+        out, _, *hits = M.moe_forward(pp["moe"], h, sub.ffn, cfg,
+                                      mesh=plan.mesh, dp_axes=plan.dp_axes,
+                                      token_mask=token_mask,
+                                      with_hits=with_hits)
     else:
-        out = F.ffn_forward(pp["ffn"], h, sub.ffn, cfg)
-    return x + out
+        out, hits = F.ffn_forward(pp["ffn"], h, sub.ffn, cfg), none
+    return (x + out, *hits) if with_hits else x + out
 
 
 def _sub_decode(pp: dict, x: jax.Array, cc: dict, sub: SubDef,
@@ -397,13 +454,21 @@ def _sub_decode(pp: dict, x: jax.Array, cc: dict, sub: SubDef,
 
 def lm_decode_step(params: dict, cache: dict, tokens: jax.Array,
                    cur_len: jax.Array, lm: LMDef, plan: ShardPlan):
-    """One-token decode. tokens: (B,1). cache leaves stacked (n_periods, ...).
+    """One-token decode. tokens: (B,1). cache leaves stacked (n_stack, ...).
     ``cur_len``: scalar shared position, or a per-slot (B,) vector — each
     batch row then appends/attends at its own length (the continuous-
     batching primitive; see repro.serve). Returns (logits, new_cache)."""
     cfg = lm.cfg
     x = embed_tokens(params, tokens, lm)
     x = plan.constrain(x, jax.sharding.PartitionSpec(plan.dp_axes, None, None))
+
+    n_lead = len(lm.lead)
+    lead_new = []
+    for j, sub in enumerate(lm.lead):
+        cc = jax.tree.map(lambda a: a[j], cache["sub_0"])
+        x, cnew = _sub_decode(params["lead"][j], x, cc, sub, cfg, plan,
+                              cur_len)
+        lead_new.append(cnew)
 
     def body(x, scan_in):
         pp, cc = scan_in
@@ -414,7 +479,13 @@ def lm_decode_step(params: dict, cache: dict, tokens: jax.Array,
             new_cc[f"sub_{i}"] = cnew
         return x, new_cc
 
+    if n_lead:
+        cache = jax.tree.map(lambda a: a[n_lead:], cache)
     x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+    if n_lead:
+        new_cache = {"sub_0": jax.tree.map(
+            lambda c, *ls: jnp.concatenate([jnp.stack(ls), c]),
+            new_cache["sub_0"], *lead_new)}
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     logits = apply_site(params["head"], x, lm.head, cfg)
     return logits, new_cache
@@ -437,7 +508,7 @@ def lm_init_cache(lm: LMDef, batch: int, max_len: int, plan: ShardPlan) -> dict:
 
     def stack(c):
         return jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (lm.n_periods,) + a.shape), c)
+            lambda a: jnp.broadcast_to(a[None], (lm.n_stack,) + a.shape), c)
 
     return {f"sub_{i}": stack(one_sub(sub))
             for i, sub in enumerate(lm.period)}
@@ -477,37 +548,40 @@ def _dpsize(plan: ShardPlan) -> int:
 # TT-site walking (prior loss, λ update, param counting)
 # ---------------------------------------------------------------------------
 
+def _sub_sites(sub: SubDef):
+    """Yield (path within the sublayer's params, SiteDef) of its sites."""
+    mk = sub.mixer_kind
+    names = {"attn_gqa": ("q", "kv", "o"),
+             "attn_mla": ("q", "q_down", "q_up", "kv_down", "k_up", "v_up",
+                          "o"),
+             "mamba": ("in_proj", "x_proj", "dt_proj", "out_proj"),
+             "rwkv6": ("r", "k", "v", "g", "o", "w_lora_a", "w_lora_b",
+                       "ffn_k", "ffn_v", "ffn_r")}[mk]
+    for n in names:
+        site = getattr(sub.mixer, n)
+        if site is not None:
+            yield ("mixer", n), site
+    if sub.ffn_kind == "ffn":
+        for n in ("gate", "up", "down"):
+            yield ("ffn", n), getattr(sub.ffn, n)
+    elif sub.ffn_kind == "moe":
+        for n in ("router", "gate", "up", "down"):
+            yield ("moe", n), getattr(sub.ffn, n)
+        if sub.ffn.shared is not None:
+            for n in ("gate", "up", "down"):
+                yield ("moe", "shared", n), getattr(sub.ffn.shared, n)
+
+
 def _walk_sites(lm: LMDef):
     """Yield (params_path_tuple, SiteDef) for every weight site."""
     if lm.embed is not None:
         yield ("embed",), lm.embed
+    for j, sub in enumerate(lm.lead):
+        for path, site in _sub_sites(sub):
+            yield ("lead", j) + path, site
     for i, sub in enumerate(lm.period):
-        base = ("layers", f"sub_{i}")
-        mk = sub.mixer_kind
-        if mk == "attn_gqa":
-            for n in ("q", "kv", "o"):
-                yield base + ("mixer", n), getattr(sub.mixer, n)
-        elif mk == "attn_mla":
-            for n in ("q_down", "q_up", "kv_down", "k_up", "v_up", "o"):
-                yield base + ("mixer", n), getattr(sub.mixer, n)
-        elif mk == "mamba":
-            for n in ("in_proj", "x_proj", "dt_proj", "out_proj"):
-                yield base + ("mixer", n), getattr(sub.mixer, n)
-        elif mk == "rwkv6":
-            for n in ("r", "k", "v", "g", "o", "w_lora_a", "w_lora_b",
-                      "ffn_k", "ffn_v", "ffn_r"):
-                yield base + ("mixer", n), getattr(sub.mixer, n)
-        if sub.ffn_kind == "ffn":
-            for n in ("gate", "up", "down"):
-                yield base + ("ffn", n), getattr(sub.ffn, n)
-        elif sub.ffn_kind == "moe":
-            for n in ("router",):
-                yield base + ("moe", n), getattr(sub.ffn, n)
-            for n in ("gate", "up", "down"):
-                yield base + ("moe", n), getattr(sub.ffn, n)
-            if sub.ffn.shared is not None:
-                for n in ("gate", "up", "down"):
-                    yield base + ("moe", "shared", n), getattr(sub.ffn.shared, n)
+        for path, site in _sub_sites(sub):
+            yield ("layers", f"sub_{i}") + path, site
     yield ("head",), lm.head
 
 

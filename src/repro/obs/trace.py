@@ -25,6 +25,10 @@ kind                  fields
 ``prefill``           rid, slot, len, dur (whole-prompt wall time)
 ``first_token``       rid, slot
 ``decode_step``       step, n_active, free_pages, dur
+``moe_hits``          step_num, rows, experts_hit, tokens_here (models with
+                      experts: live rows; held experts that got a live
+                      token and live token-expert pairs routed to held
+                      experts, each summed over the decode step's layers)
 ``preempt``           rid, slot, gen_len (generated tokens folded back)
 ``retire``            rid, slot, new_tokens, reason ("eos"|"max_new")
 ``page_alloc``        slot, page, pos (lazy growth in ``ensure_page``)

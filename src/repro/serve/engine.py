@@ -23,8 +23,14 @@ greedy decode is token-identical to the static single-request reference
 (asserted by tests/test_serve.py and tests/test_serve_state.py). MoE:
 inactive decode slots, chunked-prefill tail padding, and whole-prompt
 prefill bucket padding are all masked out of the router (zero combine
-weight -> they can never win a capacity slot against a real token; see
-``models/moe.py::_route`` and ``lm_forward(token_mask=...)``).
+weight; see ``models/moe.py::_route`` and ``lm_forward(token_mask=...)``),
+and every MoE sublayer is served dropless (``models/lm.py::dropless``), so
+a token's output depends on no other token of its batch or prefill chunk.
+
+Leading layers (``LMDef.lead``, e.g. Moonlight's dense layer 0) run before
+the layer scan on the first entries of the same stacked pool; chunked
+prefill, the prefix cache and speculative decoding scan the periods only
+and refuse such a model.
 
 Archs with recurrent state ignore ``prefill_bucket`` and pad no prefill
 chunks: a pad token would contaminate the scan-carried state (attention can
@@ -37,10 +43,10 @@ an open roadmap item.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from dataclasses import dataclass
-from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -51,7 +57,8 @@ from ..numerics import NumericsPolicy
 from ..models import attention as A
 from ..models import ssm as S
 from ..models.common import apply_site, rms_norm
-from ..models.lm import LMDef, embed_tokens, lm_forward, sub_ffn_decode
+from ..models.lm import (LMDef, dropless, embed_tokens, lm_forward,
+                         sub_ffn_decode)
 from ..obs.trace import span, step_span
 from ..sharding import ShardPlan
 from . import kv_cache as KC
@@ -106,12 +113,6 @@ class EngineConfig:
                                 # (whole-prompt + chunk widths); LRU-evicted
                                 # beyond it (serve/bucketing.py). 0:
                                 # unbounded (the pre-policy behavior)
-    moe_capacity_by_prompt: bool = False
-                                # MoE chunked-prefill capacity parity:
-                                # derive expert capacity from the FULL
-                                # prompt length instead of the visible
-                                # chunk, so chunked prefill routes like
-                                # whole-prompt at capacity-bound loads
     spec_k: int = 0             # speculative decoding: draft tokens
                                 # proposed per step (0: off). Needs a draft
                                 # model (Engine(..., draft=(lm, params)));
@@ -139,15 +140,30 @@ def _project(pm: dict, h: jax.Array, sub, cfg, positions: jax.Array):
             {"c_kv": c_new, "k_rope": kr_new})
 
 
+def _attention(pm: dict, qd: dict, kv: dict, sub, cfg,
+               positions: jax.Array) -> jax.Array:
+    """Attention over gathered (dequantized) cache views, before the
+    output projection."""
+    if sub.mixer_kind == "attn_gqa":
+        return A.gqa_attend(qd["q"], kv["k"], kv["v"], sub.mixer, positions)
+    return A.mla_attend(pm, qd["q_abs"], qd["q_rope"], kv["c_kv"],
+                        kv["k_rope"], sub.mixer, cfg, positions)
+
+
 def _attend(pm: dict, qd: dict, kv: dict, sub, cfg,
             positions: jax.Array) -> jax.Array:
     """Attention over gathered (dequantized) cache views + output proj."""
-    if sub.mixer_kind == "attn_gqa":
-        out = A.gqa_attend(qd["q"], kv["k"], kv["v"], sub.mixer, positions)
-    else:
-        out = A.mla_attend(pm, qd["q_abs"], qd["q_rope"], kv["c_kv"],
-                           kv["k_rope"], sub.mixer, cfg, positions)
-    return apply_site(pm["o"], out, sub.mixer.o, cfg)
+    return apply_site(pm["o"], _attention(pm, qd, kv, sub, cfg, positions),
+                      sub.mixer.o, cfg)
+
+
+def _gather_scope(sub):
+    """The named scope of a decode sublayer's gather-path attention:
+    ``repro.ops.mla_attention`` over MLA's latent gather, absorbed
+    attention and value up-projection; none for GQA."""
+    if sub.mixer_kind == "attn_mla":
+        return jax.named_scope("repro.ops.mla_attention")
+    return contextlib.nullcontext()
 
 
 # ---------------------------------------------------------------------------
@@ -168,6 +184,13 @@ class Engine:
                 "frontend (vision/audio) serving is an open roadmap item")
         for sub in lm.period:
             KC.kv_feature_shapes(sub)   # raises for unknown mixer kinds
+        if lm.lead and (ecfg.prefill_chunk > 0 or ecfg.prefix_cache
+                        or ecfg.spec_k > 0):
+            raise NotImplementedError(
+                "chunked prefill, the prefix cache and speculative decoding "
+                "scan the layer periods only: a model with leading layers "
+                "serves with whole-prompt prefill")
+        lm = dropless(lm)
         # per-sublayer routing: attention -> paged KV pool, SSM/RWKV ->
         # slot-indexed recurrent-state cache
         self._attn_keys = tuple(
@@ -227,6 +250,9 @@ class Engine:
         self._health_kv = health and pcfg.quantized and bool(self._attn_keys)
         self._health_state = health and squant and bool(self._state_keys)
         self._health = self._health_kv or self._health_state
+        # held-expert hits of each decode step (engine event ``moe_hits``):
+        # Python-gated so a model without experts compiles the same step
+        self._moe_hits = any(sub.ffn_kind == "moe" for sub in lm.period)
         # prefix sharing needs per-token paged memory: attention-only archs
         # opt in; any recurrent sublayer routes every request down the
         # ordinary full-prefill miss path (the cache is simply absent)
@@ -262,32 +288,27 @@ class Engine:
         self._completions: dict[int, Completion] = {}
         self._orig_prompt: dict[int, list[int]] = {}
 
-        def make_prefill(key):
+        def make_prefill(length):
             """Whole-prompt prefill (the model's own forward): numerically
-            the static-serving reference. One wrapper per (padded length,
-            MoE capacity override) so the compile cache can evict whole
-            executables; ``prefill_bucket`` bounds how many keys occur.
-            Bucket padding is masked out of the MoE router via
-            ``token_mask``."""
-            _, cap = key
+            the static-serving reference. One wrapper per padded length so
+            the compile cache can evict whole executables;
+            ``prefill_bucket`` bounds how many lengths occur. Bucket
+            padding is masked out of the MoE router via ``token_mask``."""
 
             def prefill(params, tokens, length):
                 mask = (jnp.arange(tokens.shape[1]) < length)[None]
                 logits, _, cache = lm_forward(params, lm, self.plan,
                                               tokens=tokens,
                                               return_cache=True,
-                                              token_mask=mask,
-                                              capacity_tokens=cap)
+                                              token_mask=mask)
                 return logits[0, length - 1][None], cache
 
             return jax.jit(prefill)
 
-        def make_chunk(key):
-            """Chunked-prefill step, one wrapper per (chunk width, MoE
-            capacity override) — same eviction story as make_prefill."""
-            _, cap = key
-            return jax.jit(partial(self._chunk_impl, capacity_tokens=cap),
-                           donate_argnums=(1, 2))
+        def make_chunk(width):
+            """Chunked-prefill step, one wrapper per chunk width — same
+            eviction story as make_prefill."""
+            return jax.jit(self._chunk_impl, donate_argnums=(1, 2))
 
         # bounded LRUs of live jitted prefill shapes (serve/bucketing.py);
         # the decode step is a single fixed shape and never evicts
@@ -316,6 +337,11 @@ class Engine:
                              "Engine(..., draft=(draft_lm, draft_params))")
         if self._spec:
             dlm, dparams = draft
+            if dlm.lead:
+                raise NotImplementedError(
+                    "speculative decoding scans the draft's layer periods "
+                    "only: a draft with leading layers is not supported")
+            dlm = dropless(dlm)
             if self._state_keys:
                 raise NotImplementedError(
                     "speculative decoding needs an attention-only TARGET: "
@@ -424,7 +450,9 @@ class Engine:
         the sublayer's stacked (L, P+1, page, *feat) pool leaves and
         ``layer`` the scan's layer index: the append scatters into the
         stack and the attention reads it at ``layer``, so no per-layer slab
-        is sliced out or written back. Returns (x, updated leaves)."""
+        is sliced out or written back. Returns (x, updated leaves), and
+        with MoE hits on (``self._moe_hits``) the sublayer's int32 [held
+        experts hit, tokens routed to them] third."""
         cfg = self.lm.cfg
         h = rms_norm(x, pp["norm1"]["scale"], cfg.norm_eps)
         positions = A.len_positions(lens, x.shape[0])
@@ -451,33 +479,45 @@ class Engine:
                                                   d.real_heads * d.head_dim)
             out = apply_site(pp["mixer"]["o"], attn, d.o, cfg)
         else:
-            kv = {name: KC.gather_slots(new_dsub[name], ssub[name], table,
-                                        self.pcfg, h.dtype, layer=layer)
-                  for name in new_dsub}
-            out = _attend(pp["mixer"], qd, kv, sub, cfg, positions)
-        x = x + out
-        # inactive slots are masked out of the MoE router: their junk
-        # tokens must not consume expert capacity (ROADMAP item)
-        return sub_ffn_decode(pp, x, sub, cfg, self.plan,
-                              token_mask=active[:, None]), new_dsub
+            with _gather_scope(sub):
+                kv = {name: KC.gather_slots(new_dsub[name], ssub[name],
+                                            table, self.pcfg, h.dtype,
+                                            layer=layer)
+                      for name in new_dsub}
+                out = _attention(pp["mixer"], qd, kv, sub, cfg, positions)
+            out = apply_site(pp["mixer"]["o"], out, sub.mixer.o, cfg)
+        x, *hits = self._ffn(pp, x + out, sub, active)
+        return (x, new_dsub, *hits)
+
+    def _ffn(self, pp, x, sub, active):
+        """The FFN half of a decode sublayer: (x,), and with MoE hits on
+        (x, [held experts hit, tokens routed to them]). Inactive slots are
+        masked out of the MoE router: their junk tokens count as no one's
+        and route nowhere."""
+        if self._moe_hits:
+            return sub_ffn_decode(pp, x, sub, self.lm.cfg, self.plan,
+                                  token_mask=active[:, None],
+                                  with_hits=True)
+        return (sub_ffn_decode(pp, x, sub, self.lm.cfg, self.plan,
+                               token_mask=active[:, None]),)
 
     def _sub_decode_state(self, pp, x, sd, ss, active, sub, health=None):
         """One recurrent sublayer of the batched decode step: dequantize
         every slot's state, advance one token through the mixer's
         single-step entry point, requantize active lanes (inactive lanes
-        keep their stored codes + scale)."""
+        keep their stored codes + scale). With MoE hits on, a mamba
+        sublayer's hits third, as ``_sub_decode``'s."""
         cfg = self.lm.cfg
         shapes = SC.state_feature_shapes(sub, cfg)
         state = {name: SC.read_layer(sd[name], ss[name],
                                      SC.natural_dtype(kind, cfg), self.scfg)
                  for name, (_, kind) in shapes.items()}
         h = rms_norm(x, pp["norm1"]["scale"], cfg.norm_eps)
+        hits = []
         if sub.mixer_kind == "mamba":
             out, new_state = S.mamba_decode_step(pp["mixer"], h, sub.mixer,
                                                  cfg, state)
-            x = x + out
-            x = sub_ffn_decode(pp, x, sub, cfg, self.plan,
-                               token_mask=active[:, None])
+            x, *hits = self._ffn(pp, x + out, sub, active)
         else:   # rwkv6: time-mix + channel-mix are the whole sublayer
             out, st1 = S.rwkv6_time_mix_step(pp["mixer"], h, sub.mixer, cfg,
                                              state)
@@ -496,7 +536,7 @@ class Engine:
             nd[name], ns[name] = SC.write_layer(sd[name], ss[name],
                                                 new_state[name], active,
                                                 self.scfg)
-        return x, (nd, ns)
+        return (x, (nd, ns), *hits)
 
     def _decode_impl(self, params, pool, spool, table, lens, active, tokens):
         """One batched decode step. tokens: (B,1); lens/active: (B,).
@@ -517,43 +557,60 @@ class Engine:
         slot and small, and stays xs/ys."""
         lm = self.lm
         x = embed_tokens(params, tokens, lm)
+        data, scales = pool["data"], pool["scale_log2"]
+        n_lead = len(lm.lead)
+        lead_hits = []
+        if n_lead:
+            # leading layers: pool entries 0..n_lead-1 of the one stack
+            # (quant-health counts the scanned layers only)
+            data = dict(data)
+            for j, sub in enumerate(lm.lead):
+                res = self._sub_decode(
+                    params["lead"][j], x, data["sub_0"],
+                    {n: a[j] for n, a in scales["sub_0"].items()}, table,
+                    lens, active, sub, jnp.int32(j))
+                x, data["sub_0"] = res[:2]
+                lead_hits += res[2:]
+            scales = jax.tree.map(lambda a: a[n_lead:], scales)
 
         def body(carry, scan_in):
             x, data = carry
             layer, pp, sl, sd, ss = scan_in
             data, snew_d, snew_s = dict(data), {}, {}
             hc = {"kv": [], "state": []} if self._health else None
+            hits = []
             for i, sub in enumerate(lm.period):
                 key = f"sub_{i}"
                 if sub.mixer_kind in ("mamba", "rwkv6"):
-                    x, (nd, ns) = self._sub_decode_state(
+                    x, (nd, ns), *h = self._sub_decode_state(
                         pp[key], x, sd[key], ss[key], active, sub, health=hc)
+                    hits += h
                     snew_d[key], snew_s[key] = nd, ns
                 else:
-                    x, data[key] = self._sub_decode(
+                    x, data[key], *h = self._sub_decode(
                         pp[key], x, data[key], sl[key], table, lens, active,
                         sub, layer, health=hc)
+                    hits += h
                     snew_d[key], snew_s[key] = sd[key], ss[key]
+            ys = (snew_d, snew_s)
             if self._health:
                 z32 = jnp.asarray(0, jnp.int32)
                 zf = jnp.asarray(0.0, jnp.float32)
-                h = (sum((s[0] for s in hc["kv"]), z32),
-                     sum((s[1] for s in hc["kv"]), z32),
-                     sum((s[0] for s in hc["state"]), z32),
-                     sum((s[1] for s in hc["state"]), z32),
-                     sum((s[2] for s in hc["state"]), zf),
-                     sum((s[3] for s in hc["state"]), zf))
-                return (x, data), (snew_d, snew_s, h)
-            return (x, data), (snew_d, snew_s)
+                ys += ((sum((s[0] for s in hc["kv"]), z32),
+                        sum((s[1] for s in hc["kv"]), z32),
+                        sum((s[0] for s in hc["state"]), z32),
+                        sum((s[1] for s in hc["state"]), z32),
+                        sum((s[2] for s in hc["state"]), zf),
+                        sum((s[3] for s in hc["state"]), zf)),)
+            if self._moe_hits:
+                ys += (sum(hits, jnp.zeros((2,), jnp.int32)),)
+            return (x, data), ys
 
         (x, new_data), ys = jax.lax.scan(
-            body, (x, pool["data"]),
-            (jnp.arange(lm.n_periods, dtype=jnp.int32), params["layers"],
-             pool["scale_log2"], spool["data"], spool["scale_log2"]))
-        if self._health:
-            new_sdata, new_sscale, h = ys
-        else:
-            new_sdata, new_sscale = ys
+            body, (x, data),
+            (jnp.arange(n_lead, lm.n_stack, dtype=jnp.int32),
+             params["layers"], scales, spool["data"], spool["scale_log2"]))
+        new_sdata, new_sscale = ys[:2]
         x = rms_norm(x, params["final_norm"]["scale"], lm.cfg.norm_eps)
         logits = apply_site(params["head"], x, lm.head, lm.cfg)
         out = (logits[:, 0],
@@ -564,21 +621,19 @@ class Engine:
             # per-layer ys stacked on axis 0: fold to per-step totals
             keys = ("kv_clipped", "kv_total", "state_clipped", "state_total",
                     "state_drift_sum", "state_drift_n")
-            out = out + ({k: jnp.sum(v) for k, v in zip(keys, h)},)
+            out = out + ({k: jnp.sum(v) for k, v in zip(keys, ys[2])},)
+        if self._moe_hits:
+            # [held experts hit, tokens routed to them], summed over layers
+            out = out + (jnp.sum(ys[-1], axis=0) + sum(lead_hits),)
         return out
 
     def _chunk_impl(self, params, pool, spool, tokens, table, slot, start,
-                    valid_len, capacity_tokens=None):
+                    valid_len):
         """Chunked-prefill step for one slot. Attention sublayers write the
         chunk's K/V into the pool and attend over the slot's full history;
         recurrent sublayers scan the chunk from the slot's carried state and
         write the end-of-chunk state back (stateful archs pad no chunks, so
-        ``valid_len == S`` for them). tokens: (1,S).
-
-        ``capacity_tokens`` (static, from the compile-cache key): MoE expert
-        capacity derives from this token count instead of the visible chunk
-        — the capacity-parity mode that makes chunked routing match
-        whole-prompt at capacity-bound loads."""
+        ``valid_len == S`` for them). tokens: (1,S)."""
         lm = self.lm
         cfg = lm.cfg
         s = tokens.shape[1]
@@ -602,8 +657,7 @@ class Engine:
             x = x + _attend(spp["mixer"], qd, kv, sub, cfg, positions)
             # chunk tail padding is masked out of the MoE router
             x = sub_ffn_decode(spp, x, sub, cfg, self.plan,
-                               token_mask=chunk_mask,
-                               capacity_tokens=capacity_tokens)
+                               token_mask=chunk_mask)
             return x, nd, ns
 
         def state_sub(x, spp, sdsub, sssub, sub):
@@ -618,8 +672,7 @@ class Engine:
                                               cfg, st)
                 x = x + out
                 x = sub_ffn_decode(spp, x, sub, cfg, self.plan,
-                                   token_mask=chunk_mask,
-                                   capacity_tokens=capacity_tokens)
+                                   token_mask=chunk_mask)
             else:   # rwkv6
                 out, st1 = S.rwkv6_time_mix(spp["mixer"], h, sub.mixer, cfg,
                                             st)
@@ -938,8 +991,10 @@ class Engine:
                             max_new=max_new_tokens)
         return rid
 
-    def _sample(self, logits: jax.Array, slots: list[int]) -> np.ndarray:
-        """Sample one token per row of ``logits`` with the slots' params."""
+    def _sample(self, logits: jax.Array, slots: list[int], also=None):
+        """Sample one token per row of ``logits`` with the slots' params.
+        With ``also`` (a device array), fetch it in the same read as the
+        tokens and return (tokens, also) as numpy."""
         sp = [self.sched.slots[s].req.sampling if self.sched.slots[s]
               else SamplingParams() for s in slots]
         key = jax.random.fold_in(self._key, self._nsample)
@@ -949,6 +1004,8 @@ class Engine:
             jnp.asarray([p.temperature for p in sp], jnp.float32),
             jnp.asarray([p.top_k for p in sp], jnp.int32),
             jnp.asarray([p.top_p for p in sp], jnp.float32))
+        if also is not None:
+            return jax.device_get((toks, also))
         return np.asarray(toks)
 
     def _prefill_plan(self, st) -> list[tuple[int, int, int]]:
@@ -989,9 +1046,6 @@ class Engine:
             # today — and the donated jit makes it an in-place scatter,
             # not a pool copy.
             self.spool = self._reset_state_jit(self.spool, jnp.int32(slot))
-        # MoE capacity-parity mode: every prefill shape of this request
-        # (whole or chunked) derives expert capacity from the full prompt
-        cap = plen if self.ecfg.moe_capacity_by_prompt else None
         resume = st.prefix_len
         if resume > 0:
             # prefix-cache hit: positions < resume are already resident on
@@ -1030,11 +1084,10 @@ class Engine:
                 # archs run exact-length (a pad token would contaminate the
                 # scan-carried state; see module docstring) — bucket
                 # padding applies to attention-only archs, masked out of
-                # MoE capacity via lm_forward's token_mask.
+                # the MoE router via lm_forward's token_mask.
                 tok_arr = jnp.asarray(padded, jnp.int32)[None]
-                last_logits, cache = self._prefill_fns.get(
-                    (len(padded), cap))(self.params, tok_arr,
-                                        jnp.int32(len(toks)))
+                last_logits, cache = self._prefill_fns.get(len(padded))(
+                    self.params, tok_arr, jnp.int32(len(toks)))
                 if self._attn_keys:
                     self.pool = self._write_prefill_jit(
                         self.pool, {k: cache[k] for k in self._attn_keys},
@@ -1051,7 +1104,7 @@ class Engine:
                 # chunking is off) so compiled shapes stay bounded
                 tok_arr = jnp.asarray(padded, jnp.int32)[None]
                 last_logits, self.pool, self.spool = self._chunk_fns.get(
-                    (len(padded), cap))(
+                    len(padded))(
                     self.params, self.pool, self.spool, tok_arr, table,
                     jnp.int32(slot), jnp.int32(c0), jnp.int32(len(toks)))
         self.metrics.prefill(plen, computed=plen - resume)
@@ -1161,17 +1214,17 @@ class Engine:
             active = jnp.asarray(sched.active_mask())
             tokens = jnp.asarray(sched.tokens_vector())
             t0 = self.trace.clock() if self.trace is not None else 0.0
-            health = None
-            if self._health:
-                logits, self.pool, self.spool, health = self._decode_jit(
-                    self.params, self.pool, self.spool, table, lens, active,
-                    tokens)
-            else:
-                logits, self.pool, self.spool = self._decode_jit(
-                    self.params, self.pool, self.spool, table, lens, active,
-                    tokens)
+            logits, self.pool, self.spool, *extra = self._decode_jit(
+                self.params, self.pool, self.spool, table, lens, active,
+                tokens)
+            health = extra.pop(0) if self._health else None
+            hits = extra.pop(0) if self._moe_hits else None
         with span("engine.sync"):
-            toks = self._sample(logits, list(range(self.pcfg.num_slots)))
+            if hits is None:
+                toks = self._sample(logits, list(range(self.pcfg.num_slots)))
+            else:
+                toks, hits = self._sample(
+                    logits, list(range(self.pcfg.num_slots)), also=hits)
         with span("engine.bookkeeping"):
             dur = (self.trace.clock() - t0) if self.trace is not None \
                 else None
@@ -1190,6 +1243,12 @@ class Engine:
                 self.trace.emit("decode_step", step=self.metrics.decode_steps,
                                 n_active=len(active_slots),
                                 free_pages=free_pages, dur=dur)
+                if hits is not None:
+                    self.trace.emit("moe_hits",
+                                    step_num=self.metrics.decode_steps,
+                                    rows=len(active_slots),
+                                    experts_hit=int(hits[0]),
+                                    tokens_here=int(hits[1]))
             if health is not None:
                 if self._health_kv:
                     self.metrics.record_health(

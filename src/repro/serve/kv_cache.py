@@ -105,26 +105,84 @@ def kv_feature_shapes(sub) -> dict[str, tuple[int, ...]]:
     raise ValueError(f"unknown mixer kind {sub.mixer_kind!r}")
 
 
+LANES = 128
+
+
+def page_shape(page_size: int, feat: tuple[int, ...]) -> tuple[int, ...]:
+    """Storage shape of one page of a cached tensor whose tokens each hold
+    ``feat``: (page, *feat), or, for one feature axis narrower than a row
+    of 128 lanes that tiles it, the page's tokens packed row-major into
+    such rows, (page * d / 128, 128) -- the same values in the same order.
+
+    The TPU tiles the last two axes of an array by rows of 128 lanes. A
+    (page, 64) page (MLA's rope key) would fill half of each row, so the
+    runtime lays such a leaf out with the page axis minor instead, and
+    every program that indexes pages relays the whole leaf: two copies of
+    it a decode step, temporaries that grow with the pool. Packed, the leaf
+    keeps the row-major layout the page gathers and token scatters use."""
+    if (len(feat) == 1 and feat[0] < LANES and LANES % feat[0] == 0
+            and page_size * feat[0] % LANES == 0):
+        return (page_size * feat[0] // LANES, LANES)
+    return (page_size,) + tuple(feat)
+
+
+def _packed(leaf: jax.Array, n_lead: int, pcfg: PoolConfig) -> bool:
+    """Whether a pool leaf with ``n_lead`` axes before its pages' own
+    (page axis, or layer and page axes) stores packed lane rows."""
+    s = leaf.shape[n_lead:]
+    return len(s) == 2 and s[-1] == LANES and s[0] != pcfg.page_size
+
+
+def _page_feat(leaf: jax.Array, n_lead: int, pcfg: PoolConfig
+               ) -> tuple[int, ...]:
+    """The per-token feature shape a pool leaf stores."""
+    s = leaf.shape[n_lead:]
+    if _packed(leaf, n_lead, pcfg):
+        return (s[0] * LANES // pcfg.page_size,)
+    return tuple(s[1:])
+
+
+def _set_tokens(leaf: jax.Array, lead: tuple, pages: jax.Array,
+                offs: jax.Array, vals: jax.Array, pcfg: PoolConfig
+                ) -> jax.Array:
+    """``leaf.at[*lead, pages, offs].set(vals)``: write each token's values
+    at (page, offset) in the leaf's page layout. ``lead`` holds the index
+    of each axis before the page axis (the layer, or none); the index
+    arrays broadcast against each other to ``vals.shape[:-len(feat)]``.
+    A packed leaf takes each token's d values at their lanes of its row:
+    the TPU compiler emits one scatter either way (a windowed
+    ``lax.scatter`` into part of a row becomes a loop, one token a turn)."""
+    if not _packed(leaf, len(lead) + 1, pcfg):
+        return leaf.at[lead + (pages, offs)].set(vals)
+    d = vals.shape[-1]
+    flat = offs * d
+    ex = lambda a: jnp.asarray(a)[..., None]
+    idx = tuple(ex(a) for a in lead) + (
+        ex(pages), ex(flat // LANES), ex(flat % LANES) + jnp.arange(d))
+    return leaf.at[idx].set(vals)
+
+
 def init_pool(lm, pcfg: PoolConfig) -> dict:
     """Allocate the device half of the pool for every attention sublayer of
     ``lm`` (recurrent sublayers get empty dicts: their state lives in the
     ``state_cache`` pool, keyed identically for the engine's layer scan).
 
-    Returns {"data": {sub_i: {name: (L, P+1, page, *feat) int8|dtype}},
-             "scale_log2": {sub_i: {name: (L, num_slots) f32}}}.
+    Returns {"data": {sub_i: {name: (L, P+1, *page_shape) int8|dtype}},
+             "scale_log2": {sub_i: {name: (L, num_slots) f32}}}, L =
+    ``lm.n_stack`` (leading layers take the first entries of sub_0).
     ``scale_log2`` is carried (zero) in fp mode too so the step function's
     pytree structure is independent of the numerics mode.
     """
     fp_dtype = jnp.dtype(lm.cfg.dtype)
     store = jnp.int8 if pcfg.quantized else fp_dtype
-    L = lm.n_periods
+    L = lm.n_stack
     data: dict = {}
     scale: dict = {}
     for i, sub in enumerate(lm.period):
         feats = kv_feature_shapes(sub)
         data[f"sub_{i}"] = {
-            name: jnp.zeros((L, pcfg.total_pages + 1, pcfg.page_size) + f,
-                            store)
+            name: jnp.zeros((L, pcfg.total_pages + 1)
+                            + page_shape(pcfg.page_size, f), store)
             for name, f in feats.items()}
         scale[f"sub_{i}"] = {
             name: jnp.zeros((L, pcfg.num_slots), jnp.float32)
@@ -147,7 +205,7 @@ def pool_bytes_fp32(pool: dict) -> int:
 
 def page_nbytes(pool: dict, pcfg: PoolConfig) -> int:
     """Physical bytes of ONE page summed across every cached tensor of
-    every layer (data leaves are (L, P+1, page, *feat): each of the P+1
+    every layer (data leaves are (L, P+1, *page_shape): each of the P+1
     physical pages owns an equal 1/(P+1) slice).  Per-slot scale rows are
     page-independent and excluded.  This is the unit that turns the page
     table's logical-vs-physical mapped counts into verified bytes
@@ -198,15 +256,16 @@ def gather_slots(data_l: jax.Array, scale_l: jax.Array, table: jax.Array,
                  ) -> jax.Array:
     """Materialize every slot's cache view for one layer.
 
-    data_l: (P+1, page, *feat), or the stacked (L, P+1, page, *feat) leaf
+    data_l: (P+1, *page_shape), or the stacked (L, P+1, *page_shape) leaf
     with ``layer`` (gathered straight out of the stack, no per-layer slab);
     scale_l: (num_slots,); table: (B, pages_per_slot). Returns (B,
     T=max_len, *feat) in ``dtype`` (dequantized on read).
     """
     idx = table if layer is None else (layer, table)
-    g = data_l[idx]                                      # (B, pp, page, *f)
+    feat = _page_feat(data_l, 1 if layer is None else 2, pcfg)
+    g = data_l[idx]                                   # (B, pp, *page_shape)
     b = table.shape[0]
-    g = g.reshape((b, pcfg.max_len) + g.shape[3:])
+    g = g.reshape((b, pcfg.max_len) + feat)
     if pcfg.quantized:
         return dequantize(g, scale_l.reshape((b,) + (1,) * (g.ndim - 1)),
                           dtype)
@@ -257,8 +316,8 @@ def append_token(data_l: jax.Array, scale_l: jax.Array, new: jax.Array,
 
     new: (B, 1, *feat) fp; inactive slots are redirected to the trash page.
     Decode appends reuse the slot's prefill scale (clipping into its range).
-    data_l is one layer's (P+1, page, *feat) pages, or the stacked (L, P+1,
-    page, *feat) leaf with ``layer``: one scatter at [layer, page, off],
+    data_l is one layer's (P+1, *page_shape) pages, or the stacked (L, P+1,
+    *page_shape) leaf with ``layer``: one scatter at [layer, page, off],
     in place when the caller carries the pool.
     """
     b = new.shape[0]
@@ -272,8 +331,8 @@ def append_token(data_l: jax.Array, scale_l: jax.Array, new: jax.Array,
                         pcfg.bits)
     else:
         vals = vals.astype(data_l.dtype)
-    idx = (pages, offs) if layer is None else (layer, pages, offs)
-    return data_l.at[idx].set(vals)
+    lead = () if layer is None else (layer,)
+    return _set_tokens(data_l, lead, pages, offs, vals, pcfg)
 
 
 def append_tokens(data_l: jax.Array, scale_l: jax.Array, new: jax.Array,
@@ -303,7 +362,7 @@ def append_tokens(data_l: jax.Array, scale_l: jax.Array, new: jax.Array,
                         pcfg.bits)
     else:
         vals = new.astype(data_l.dtype)
-    return data_l.at[pages, offs].set(vals)
+    return _set_tokens(data_l, (), pages, offs, vals, pcfg)
 
 
 def append_health(new: jax.Array, scale_l: jax.Array, active: jax.Array,
@@ -341,7 +400,7 @@ def write_chunk(data_l: jax.Array, scale_l: jax.Array, vals: jax.Array,
         vals = quantize(vals, scale_l[slot][None], pcfg.bits)
     else:
         vals = vals.astype(data_l.dtype)
-    return data_l.at[pages, offs].set(vals), scale_l
+    return _set_tokens(data_l, (), pages, offs, vals, pcfg), scale_l
 
 
 class PageRefs:
@@ -442,7 +501,18 @@ def write_prefill(pool: dict, cache: dict, table_row: jax.Array,
                 vals = quantize(vals, step[:, None], pcfg.bits)
             else:
                 vals = vals.astype(new_d[name].dtype)
-            new_d[name] = new_d[name].at[:, pages, offs].set(vals)
+            if vals.ndim == 3:
+                # one feature axis (MLA's latent leaves): with the layer a
+                # slice of the scatter, the TPU compiler lays the leaf out
+                # again around it, a pool-sized temporary, so the layer is
+                # an index too; (heads, dim) leaves scatter in place as a
+                # slice (AOT compile for a v5e)
+                layers = jnp.arange(vals.shape[0])[:, None]
+                new_d[name] = _set_tokens(new_d[name], (layers,),
+                                          pages[None], offs[None], vals,
+                                          pcfg)
+            else:
+                new_d[name] = new_d[name].at[:, pages, offs].set(vals)
         data[key] = new_d
         scale[key] = new_s
     return {"data": data, "scale_log2": scale}

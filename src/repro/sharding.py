@@ -53,6 +53,7 @@ def _div(n: int, mesh: Mesh | None, axis) -> bool:
 _REPLICATED_LEAVES = frozenset({
     "b", "bias", "scale", "wscale_log2", "ln_x_scale",
     "w0", "u", "mu_x", "mu_ffn", "A_log", "D", "conv_w", "conv_b",
+    "router_bias",
 })
 
 

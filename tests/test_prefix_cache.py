@@ -11,7 +11,9 @@
 (e) stateful archs (recurrent sublayers) bypass the cache entirely,
 (f) the bounded compile cache evicts jitted prefill shapes without
     changing tokens; MoE chunked-prefill capacity parity routes chunks
-    like whole-prompt at capacity-bound loads.
+    like whole-prompt at capacity-bound loads (model level), and the
+    engine, serving MoE dropless, gives chunked and whole-prompt prefill
+    the same tokens.
 """
 import jax
 import jax.numpy as jnp
@@ -377,10 +379,14 @@ def test_moe_capacity_parity_unit():
 
 
 def test_moe_engine_chunked_parity_flag():
-    """Engine-level: with moe_capacity_by_prompt on, chunked prefill and
-    whole-prompt prefill produce identical tokens on an MoE arch (the
-    static capacity key threads through both compiled paths)."""
+    """Engine-level: chunked prefill and whole-prompt prefill produce
+    identical tokens on an MoE arch whose config routes with a capacity:
+    the engine serves every MoE sublayer dropless, so no token's expert
+    output depends on which other tokens share its prefill pass (the
+    whole 24-token prompt overflows an expert's capacity of 8 here, where
+    each 8-token chunk cannot)."""
     cfg, lm, params = _setup("moonshot-v1-16b")
+    assert not cfg.moe.dropless
     pcfg = PoolConfig(num_slots=2, page_size=8, pages_per_slot=4,
                       quantized=False)
     rng = np.random.RandomState(29)
@@ -388,8 +394,7 @@ def test_moe_engine_chunked_parity_flag():
     outs = []
     for chunk in (0, 8):
         eng = Engine(lm, params,
-                     EngineConfig(pool=pcfg, prefill_chunk=chunk,
-                                  moe_capacity_by_prompt=True), PLAN)
+                     EngineConfig(pool=pcfg, prefill_chunk=chunk), PLAN)
         rid = eng.submit(prompt, max_new_tokens=6)
         outs.append(eng.run()[rid].tokens)
     assert outs[0] == outs[1]

@@ -119,3 +119,44 @@ def test_pe2(one_chip, compiled_mode):
 def test_pe3(one_chip, compiled_mode):
     _compile(ops.pe3, one_chip, ((256, 64), jnp.float32),
              ((256, 128), jnp.float32))
+
+
+def test_mla_decode_step_moves_no_pool_sized_copy(one_chip):
+    """Moonlight's decode step at its latent widths (512 latent, 64 rope,
+    int8 pages of 16), compiled for one v5e: no instruction copies,
+    broadcasts, slices or updates a whole pool leaf, and the temporaries
+    stay under the smallest leaf. An int8 page of 64-wide rope keys is laid
+    out page-minor by the TPU runtime unless its tokens are packed into
+    128-lane rows (``kv_cache.page_shape``), and the step then copies the
+    leaf in and out whole, temporaries that grow with the pool."""
+    import dataclasses
+    import numpy as np
+
+    import repro.configs as C
+    from hlo_pool import pool_shapes, pool_sized_ops
+    from repro.models import build_lm, init_lm
+    from repro.serve import Engine, EngineConfig, PoolConfig
+    from repro.sharding import ShardPlan
+
+    cfg = C.get_reduced("moonlight-16b-a3b")
+    cfg = cfg.replace(dtype="bfloat16", remat="none", mla=dataclasses.replace(
+        C.get_config("moonlight-16b-a3b").mla))
+    lm = build_lm(cfg)
+    params = init_lm(jax.random.PRNGKey(0), lm)
+    pcfg = PoolConfig(num_slots=B, page_size=16, pages_per_slot=PP,
+                      num_pages=4096, quantized=True)
+    eng = Engine(lm, params, EngineConfig(pool=pcfg), ShardPlan(mesh=None))
+    spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=one_chip)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    compiled = eng._decode_jit.lower(
+        jax.tree.map(spec, eng.params), jax.tree.map(spec, eng.pool),
+        jax.tree.map(spec, eng.spool), i32(B, PP), i32(B),
+        jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip),
+        i32(B, 1)).compile()
+    shapes = pool_shapes(eng.pool)
+    bad = pool_sized_ops(compiled.as_text(), shapes)
+    assert not bad, "\n".join(bad)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < min(shapes.values()), (temp, shapes)
+    assert np.prod(eng.pool["data"]["sub_0"]["k_rope"].shape[-2:]) == 16 * 64
