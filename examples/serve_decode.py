@@ -48,7 +48,7 @@ def main():
     ap.add_argument("--prefill-chunk", type=int, default=0)
     ap.add_argument("--fused", action="store_true",
                     help="fused paged-attention decode (per-page in-kernel "
-                         "dequant; MLA sublayers fall back to gather)")
+                         "dequant; MLA sublayers take the latent walk)")
     args = ap.parse_args()
 
     enable_compile_cache()

@@ -16,6 +16,7 @@ import numpy as np
 from ..numerics.pallas_backend import interpret_mode as _interpret
 from ..numerics.pallas_backend import native_backend
 from ..obs.counters import record_kernel_call
+from . import mla_paged_attention as MPA
 from . import paged_attention as PA
 from . import ttm_pe1, ttm_pe2, ttm_pe3
 
@@ -229,6 +230,48 @@ def paged_attention(q: jax.Array, kdata: jax.Array, vdata: jax.Array,
         f = shard_map(f, plan.mesh, in_specs=specs + (P(),) * len(extra),
                       out_specs=qspec)
     return f(q, kdata, vdata, kscale, vscale, table, lens, *extra)
+
+
+def mla_paged_attention(q_abs: jax.Array, q_rope: jax.Array, ckv: jax.Array,
+                        krope: jax.Array, cscale: jax.Array,
+                        rscale: jax.Array, table: jax.Array,
+                        lens: jax.Array, active: jax.Array,
+                        layer: jax.Array, *, page_size: int,
+                        quantized: bool, sm_scale: float,
+                        impl: str = "auto") -> jax.Array:
+    """MLA decode attention in the latent space, straight off the stacked
+    int8 (or model-dtype) pool leaves at ``layer``, over each active slot's
+    live pages: (B, H, kv_lora) latent output, before the value
+    up-projection. See ``kernels/mla_paged_attention.py`` for layouts and
+    the numerics contract.
+
+    impl: "pallas" (the kernel; compiled on TPU, interpret elsewhere),
+    "jnp" (the same blocks as a loop in XLA), or "auto" -- the kernel where
+    Pallas is native, the loop elsewhere. Runs under the named scope
+    ``repro.ops.mla_attention``. Unsharded: the latent leaves are
+    replicated under a mesh (``ShardPlan.kv_page_spec``), where the engine
+    keeps the gather path."""
+    if impl == "auto":
+        impl = "pallas" if native_backend() else "jnp"
+    # bytes the walk may touch: every table-addressable page of both
+    # leaves (it reads the live ones only), plus q in and the latent out
+    pages = table.shape[0] * table.shape[1]
+    page_bytes = (int(np.prod(ckv.shape[-2:])) * ckv.dtype.itemsize
+                  + int(np.prod(krope.shape[-2:])) * krope.dtype.itemsize)
+    record_kernel_call(f"mla_paged_attention.{impl}",
+                       bytes_moved=pages * page_bytes
+                       + 2 * _nbytes(q_abs) + _nbytes(q_rope))
+    kw = dict(page_size=page_size, quantized=quantized, sm_scale=sm_scale)
+    args = (q_abs, q_rope, ckv, krope, cscale, rscale, table, lens, active,
+            layer)
+    with jax.named_scope("repro.ops.mla_attention"):
+        if impl == "pallas":
+            return MPA.mla_paged_attention_kernel(*args,
+                                                  interpret=_interpret(),
+                                                  **kw)
+        if impl == "jnp":
+            return MPA.mla_paged_attention_jnp(*args, **kw)
+    raise ValueError(f"unknown mla_paged_attention impl {impl!r}")
 
 
 def ttm_matvec_kernels(cores, x, spec):

@@ -425,18 +425,30 @@ def mla_attend(params: dict, q_abs: jax.Array, q_rope: jax.Array,
     """Latent-space attention. ckv: (B,T,kv_lora); kr: (B,T,rope);
     qpos: (B,S). Returns (B,S,H*v_head) pre-o-proj."""
     m = d.m
-    b, s = q_abs.shape[:2]
     t = ckv.shape[1]
     s_nope = jnp.einsum("bqhl,btl->bhqt", q_abs, ckv,
                         preferred_element_type=jnp.float32)
     s_rope = jnp.einsum("bqhd,btd->bhqt", q_rope, kr,
                         preferred_element_type=jnp.float32)
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
-    sc = (s_nope + s_rope) * scale
+    sc = (s_nope + s_rope) * mla_softmax_scale(m)
     mask = causal_len_mask(qpos, t)                       # (B, S, T)
     sc = jnp.where(mask[:, None], sc, NEG_INF)
     p = jax.nn.softmax(sc, axis=-1)
     out_lat = jnp.einsum("bhqt,btl->bqhl", p.astype(ckv.dtype), ckv)
+    return mla_value_up(params, out_lat, d, cfg)
+
+
+def mla_softmax_scale(m: MLAConfig) -> float:
+    """Score scale of absorbed MLA: 1/sqrt of the un-absorbed qk width."""
+    return 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+
+
+def mla_value_up(params: dict, out_lat: jax.Array, d: MLADef,
+                 cfg: ModelConfig) -> jax.Array:
+    """Latent attention output (B,S,H,kv_lora) -> per-head values through
+    ``v_up``, (B,S,H*v_head) pre-o-proj."""
+    m = d.m
+    b, s = out_lat.shape[:2]
     wv = _absorb_weight(params["v_up"], d.v_up, cfg)
     wv = wv.reshape(m.kv_lora_rank, d.num_heads, m.v_head_dim)
     out = jnp.einsum("bqhl,lhd->bqhd", out_lat, wv.astype(out_lat.dtype))

@@ -9,7 +9,8 @@ optionally int8-quantized pool of ``serve/kv_cache.py`` and are dequantized
 on read inside the per-layer scan.
 
 Sublayer routing: attention sublayers read/write the slot-paged KV pool
-(``serve/kv_cache.py``, gather or fused paged-attention); SSM/RWKV
+(``serve/kv_cache.py``: the gather path, or with ``fused_attention`` the
+page walk for GQA and the latent walk for MLA); SSM/RWKV
 sublayers read/write the slot-indexed recurrent-state cache
 (``serve/state_cache.py``) through the single-step decode entry points of
 ``models/ssm.py`` — so pure-SSM (rwkv6), hybrid (jamba) and all-attention
@@ -53,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import ops
 from ..numerics import NumericsPolicy
 from ..models import attention as A
 from ..models import ssm as S
@@ -94,12 +96,14 @@ class EngineConfig:
                                 # site overrides the pool's quantized/bits
                                 # knobs (one owner for the system's numerics)
     fused_attention: bool = False
-                                # decode attends via the fused paged-
-                                # attention kernel (per-page in-kernel int8
-                                # dequant + online softmax) instead of
-                                # gather_slots + attend. GQA sublayers only;
-                                # MLA sublayers keep the gather reference
-                                # (fused MLA is an open roadmap item)
+                                # decode attends straight off the paged
+                                # pool (per-page in-kernel int8 dequant +
+                                # online softmax) instead of gather_slots +
+                                # attend: GQA sublayers through the page
+                                # walk (decode and verify), MLA sublayers
+                                # through the latent walk (decode without a
+                                # mesh; MLA verify keeps the gather, see
+                                # ``Engine._fused_for``)
     fused_impl: str = "auto"    # "auto" | "pallas" | "jnp" — see
                                 # kernels/ops.py::paged_attention
     prefix_cache: bool = False
@@ -158,9 +162,10 @@ def _attend(pm: dict, qd: dict, kv: dict, sub, cfg,
 
 
 def _gather_scope(sub):
-    """The named scope of a decode sublayer's gather-path attention:
-    ``repro.ops.mla_attention`` over MLA's latent gather, absorbed
-    attention and value up-projection; none for GQA."""
+    """The named scope of a decode sublayer's MLA attention:
+    ``repro.ops.mla_attention`` over the latent gather and absorbed
+    attention, or the latent walk, and the value up-projection; none for
+    GQA."""
     if sub.mixer_kind == "attn_mla":
         return jax.named_scope("repro.ops.mla_attention")
     return contextlib.nullcontext()
@@ -438,11 +443,35 @@ class Engine:
     def _adopt_impl(self, pool, slot, snap):
         return self._ckv(KC.adopt_scales(pool, slot, snap))
 
-    def _fused_for(self, sub) -> bool:
-        """Fused-kernel eligibility of one sublayer (the fallback matrix:
-        GQA/MQA/MHA fused; MLA latent attention stays on the gather
-        reference — its absorbed-weight einsums need a dedicated kernel)."""
-        return self.ecfg.fused_attention and sub.mixer_kind == "attn_gqa"
+    def _fused_for(self, sub, verify: bool = False) -> bool:
+        """Whether one attention sublayer attends straight off the pool
+        (``fused_attention``): GQA/MQA/MHA through the page walk, in decode
+        and verify, head-sharded under a mesh; MLA through the latent walk
+        in decode without a mesh. MLA keeps the gather path under a mesh
+        (its latent leaves are replicated, ``ShardPlan.kv_page_spec``) and
+        in verify (a q-block latent walk is an open roadmap item)."""
+        if not self.ecfg.fused_attention:
+            return False
+        if sub.mixer_kind == "attn_gqa":
+            return True
+        return (sub.mixer_kind == "attn_mla" and not verify
+                and self.plan.mesh is None)
+
+    def attention_paths(self) -> dict:
+        """{mixer kind: {"decode": path, "verify": path}} of the model's
+        attention sublayers; a path is the op that attends
+        (``paged_attention``, ``mla_paged_attention``) or ``gather``."""
+        walk = {"attn_gqa": "paged_attention",
+                "attn_mla": "mla_paged_attention"}
+        out = {}
+        for sub in self.lm.lead + self.lm.period:
+            if sub.mixer_kind in walk:
+                out[sub.mixer_kind] = {
+                    phase: walk[sub.mixer_kind]
+                    if self._fused_for(sub, verify=phase == "verify")
+                    else "gather"
+                    for phase in ("decode", "verify")}
+        return out
 
     def _sub_decode(self, pp, x, dsub, ssub, table, lens, active, sub,
                     layer, health=None):
@@ -466,7 +495,23 @@ class Engine:
                                           lens, active, self.pcfg,
                                           layer=layer)
                     for name, new in newd.items()}
-        if self._fused_for(sub):
+        if self._fused_for(sub) and sub.mixer_kind == "attn_mla":
+            # latent walk: attend off the int8 latent pages, each slot's
+            # live pages only; the value up-projection stays in XLA, under
+            # the same scope
+            d = sub.mixer
+            with _gather_scope(sub):
+                lat = ops.mla_paged_attention(
+                    qd["q_abs"][:, 0], qd["q_rope"][:, 0],
+                    new_dsub["c_kv"], new_dsub["k_rope"], ssub["c_kv"],
+                    ssub["k_rope"], table, lens, active, layer,
+                    page_size=self.pcfg.page_size,
+                    quantized=self.pcfg.quantized,
+                    sm_scale=A.mla_softmax_scale(d.m),
+                    impl=self.ecfg.fused_impl)
+                out = A.mla_value_up(pp["mixer"], lat[:, None], d, cfg)
+            out = apply_site(pp["mixer"]["o"], out, d.o, cfg)
+        elif self._fused_for(sub):
             # fused path: attend straight off the int8 pages — per-page
             # dequant + online softmax inside the kernel, no gathered view
             d = sub.mixer
@@ -728,7 +773,7 @@ class Engine:
         new_dsub = {name: KC.append_tokens(dsub[name], ssub[name], new,
                                            table, lens, active, self.pcfg)
                     for name, new in newd.items()}
-        if self._fused_for(sub):
+        if self._fused_for(sub, verify=True):
             d = sub.mixer
             b, s = x.shape[:2]
             attn = KC.fused_attend(new_dsub["k"], new_dsub["v"], ssub["k"],
@@ -1281,6 +1326,7 @@ class Engine:
         if self.plan.mesh is not None:
             self.ledger.record_devices(self.pool, self.spool, self.params)
         out = self.metrics.summary()
+        out["attention_paths"] = self.attention_paths()
         mem = self.ledger.summary()
         mem["reconcile"] = self.ledger.reconcile()
         out["memory"] = mem

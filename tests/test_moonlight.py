@@ -230,6 +230,35 @@ def test_engine_serves_the_same_tokens_from_packed_pages():
     assert fp == [_static_greedy(lm, params, p, 6) for p in prompts]
 
 
+def test_engine_serves_the_same_tokens_through_the_latent_walk():
+    """The dense layer 0 and the MoE layers, from an int8 pool of packed
+    pages (latent and rope keys in 128-lane rows): the latent page walk
+    serves the gather path's greedy tokens, with requests that cross pages
+    and the walk's blocks of 8 pages (128 tokens)."""
+    cfg = _cfg()
+    lm = build_lm(cfg)
+    params = init_lm(jax.random.PRNGKey(0), lm)
+    rng = np.random.RandomState(8)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (124, 5, 17)]
+
+    def serve(fused):
+        eng = Engine(lm, params, EngineConfig(
+            pool=PoolConfig(num_slots=2, page_size=16, pages_per_slot=9,
+                            quantized=True),
+            fused_attention=fused), PLAN)
+        rids = [eng.submit(p, max_new_tokens=8) for p in prompts]
+        res = eng.run()
+        return eng, [res[r].tokens for r in rids]
+
+    eng, walked = serve(True)
+    assert eng.attention_paths()["attn_mla"]["decode"] == \
+        "mla_paged_attention"
+    assert {n: a.shape[-2:] for n, a in eng.pool["data"]["sub_0"].items()} \
+        == {"c_kv": (4, 128), "k_rope": (1, 128)}
+    assert walked == serve(False)[1]
+
+
 @pytest.mark.parametrize("ekw", [{"prefill_chunk": 8},
                                  {"prefix_cache": True}])
 def test_engine_refuses_period_only_paths(ekw):

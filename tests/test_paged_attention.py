@@ -13,7 +13,9 @@ page-scan the engine uses off-TPU) must agree with it:
     to the gather engine over staggered ragged requests (prompts and
     generations crossing page boundaries), in fp32 and int8 pools;
 (d) preemption + resume under page pressure keeps fused == gather;
-(e) MLA archs fall back to the gather reference and still match;
+(e) MLA archs take the latent page walk in decode (its own kernel,
+    ``kernels/mla_paged_attention.py``, differential tests in
+    tests/test_mla_walk.py) and still match the gather path;
 (f) q-block generalization (S query rows at positions lens..lens+S-1 with a
     per-row causal mask — chunked prefill / speculative verify): kernel vs
     oracle over S x heads x storage, BIT-locked to the jnp page-scan, and
@@ -398,9 +400,11 @@ def test_fused_engine_after_preemption_and_resume(impl):
     assert out == ref
 
 
-def test_mla_arch_falls_back_to_gather():
-    """deepseek-v2 (MLA) with the fused flag on: every sublayer takes the
-    gather reference path (the fallback matrix) and decode is unchanged."""
+def test_mla_arch_takes_the_latent_walk():
+    """deepseek-v2 (MLA) with the fused flag on: decode takes the latent
+    page walk (``ops.mla_paged_attention``) and serves the gather path's
+    greedy tokens; speculative verify keeps the gather path for MLA."""
+    from repro.obs import registry
     cfg, lm, params = _setup("deepseek-v2-236b")
     assert any(sub.mixer_kind == "attn_mla" for sub in lm.period)
     pcfg = PoolConfig(num_slots=2, page_size=8, pages_per_slot=4,
@@ -408,10 +412,15 @@ def test_mla_arch_falls_back_to_gather():
     prompts = _prompts(cfg, 2, 8, 12, seed=13)
     gens = [5, 6]
     ref, _ = _run_engine(lm, params, pcfg, prompts, gens)
+    before = registry.snapshot("kernel.mla_paged_attention.").copy()
     out, eng = _run_engine(lm, params, pcfg, prompts, gens,
                            fused_attention=True)
-    assert not any(eng._fused_for(sub) for sub in lm.period
-                   if sub.mixer_kind == "attn_mla")
+    mla = [sub for sub in lm.period if sub.mixer_kind == "attn_mla"]
+    assert all(eng._fused_for(sub) for sub in mla)
+    assert not any(eng._fused_for(sub, verify=True) for sub in mla)
+    assert eng.summary()["attention_paths"] == {
+        "attn_mla": {"decode": "mla_paged_attention", "verify": "gather"}}
+    assert registry.snapshot("kernel.mla_paged_attention.") != before
     assert out == ref
 
 
